@@ -55,6 +55,8 @@ __all__ = [
 
 #: lane width of one arena row — matches kernels/fedcet_update.py LANES.
 LANES = 1024
+#: the arena's row count is a multiple of this (the f32 sublane tile).
+SUBLANES = 8
 
 
 def _rows_of(shape: tuple) -> int:
@@ -72,11 +74,12 @@ class ArenaLayout:
 
     @classmethod
     def for_tree(cls, tree) -> "ArenaLayout":
-        """Layout for a MODEL pytree (leaves carry no client axis)."""
+        """Layout for a MODEL pytree (leaves carry no client axis; arrays
+        or ``ShapeDtypeStruct``s)."""
         leaves, treedef = jax.tree.flatten(tree)
         if not leaves:
             raise ValueError("cannot build an arena layout for an empty tree")
-        dtypes = {jnp.asarray(l).dtype for l in leaves}
+        dtypes = {jnp.result_type(l) for l in leaves}
         if len(dtypes) != 1:
             raise ValueError(
                 "arena requires a homogeneous leaf dtype (mixed dtypes would "
@@ -85,8 +88,14 @@ class ArenaLayout:
         if not jnp.issubdtype(dtype, jnp.floating):
             raise ValueError(f"arena leaves must be floating, got {dtype}")
         shapes = tuple(tuple(jnp.shape(l)) for l in leaves)
+        rows = [_rows_of(s) for s in shapes]
+        # the last leaf takes zero rows up to a multiple of the 8-row
+        # sublane tile: with an unaligned row count XLA lays a TPU
+        # [clients, rows, LANES] buffer out client-minor and copies every
+        # operand into and out of each Pallas kernel.
+        rows[-1] += -sum(rows) % SUBLANES
         return cls(treedef=treedef, shapes=shapes, dtype=dtype,
-                   rows_per_leaf=tuple(_rows_of(s) for s in shapes))
+                   rows_per_leaf=tuple(rows))
 
     @property
     def rows(self) -> int:

@@ -1397,7 +1397,7 @@ def with_telemetry(algo: RoundEngine, telemetry=True) -> RoundEngine:
 # --------------------------------------------------------- multi-round driver
 def make_round_runner(algo, grad_fn: GradFn, *, metric_fn=None,
                       repeat: bool = False, metric_with_batch: bool = False,
-                      donate: bool = False):
+                      metric_before: bool = False, donate: bool = False):
     """Build the jitted K-round scan over ``algo.round``.
 
     * ``repeat=False`` (default): the returned ``run(state, batches)`` scans
@@ -1410,8 +1410,10 @@ def make_round_runner(algo, grad_fn: GradFn, *, metric_fn=None,
     into the second return value; with ``metric_with_batch=True`` it is
     called as ``metric_fn(state, round_batches)`` instead (the per-round
     ``[tau, clients, ...]`` pytree) — this is how ``FedTrainer.fit`` keeps
-    its eval-loss series on-device inside the scan. Keep ONE runner per
-    training loop: jit caching is per function instance.
+    its eval-loss series on-device inside the scan. ``metric_before=True``
+    evaluates it on the state ENTERING each round instead, before any step
+    has seen that round's batches. Keep ONE runner per training loop: jit
+    caching is per function instance.
 
     ``donate=True`` donates the state argument (``donate_argnums=(0,)``)
     so the carry aliases in/out — for a cohort algorithm the scatter back
@@ -1441,27 +1443,23 @@ def make_round_runner(algo, grad_fn: GradFn, *, metric_fn=None,
             s = algo.round(grad_fn, s, b)
         return s, tel.finalize(tape, algo, s)
 
-    def _ys(s, b, tl):
-        m = _metric(s, b)
-        return m if tel is None else {"metric": m, "telemetry": tl}
+    def _step(s, b):
+        m = _metric(s, b) if metric_before else None
+        s, tl = _round(s, b)
+        if not metric_before:
+            m = _metric(s, b)
+        return s, (m if tel is None else {"metric": m, "telemetry": tl})
 
     donate_kw = {"donate_argnums": (0,)} if donate else {}
     if repeat:
         def run(state, batches, rounds):
-            def body(s, _):
-                s, tl = _round(s, batches)
-                return s, _ys(s, batches, tl)
-
-            return jax.lax.scan(body, state, None, length=rounds)
+            return jax.lax.scan(lambda s, _: _step(s, batches), state, None,
+                                length=rounds)
 
         return jax.jit(run, static_argnums=2, **donate_kw)
 
     def run(state, batches):
-        def body(s, b):
-            s, tl = _round(s, b)
-            return s, _ys(s, b, tl)
-
-        return jax.lax.scan(body, state, batches)
+        return jax.lax.scan(_step, state, batches)
 
     return jax.jit(run, **donate_kw)
 

@@ -36,7 +36,7 @@ def _fedcet_v_kernel(x_ref, g_ref, d_ref, o_ref, *, alpha: float):
     o_ref[...] = x - alpha * g - alpha * d
 
 
-def fedcet_v_2d(x, g, d, *, alpha: float, interpret: bool = True):
+def fedcet_v_2d(x, g, d, *, alpha: float, interpret: bool):
     """x, g, d: [rows, LANES] (pre-tiled by ops.py)."""
     rows = x.shape[0]
     rb = min(ROW_BLOCK, rows)
@@ -68,7 +68,7 @@ def _fedcet_comm4_kernel(d_ref, m_ref, mb_ref, v_ref, d_out_ref, x_out_ref,
 
 
 def fedcet_comm4_2d(d, m, m_bar, v, *, c: float, alpha: float,
-                    interpret: bool = True):
+                    interpret: bool):
     """The compressed-message aggregation pair (oracle:
     ref.fedcet_comm with ``v=``): delta comes from the WIRE message
     ``m`` while the x-update starts from the exact local ``v``.
@@ -109,9 +109,35 @@ def _round_tail_kernel(v_ref, h_ref, d_ref, u_ref, s_ref, w_ref, den_ref,
     h_out_ref[...] = h + beta * qs
 
 
+#: scoped-VMEM bytes the round tail's double-buffered blocks may take:
+#: half of the 16 MiB default scoped limit on v5e, leaving the other half
+#: for the kernel body's [C, rb, lb] temporaries.
+TAIL_BLOCK_BUDGET = 8 * 2**20
+#: the default scoped-VMEM limit; the tail asks for more only above it.
+DEFAULT_SCOPED_VMEM = 16 * 2**20
+
+
+def tail_blocks(n_clients: int, rows: int, itemsize: int) -> tuple[int, int]:
+    """(row block, lane block) of the fused round tail. Each grid step
+    keeps every client of its block resident (the cross-client reduce
+    happens in-kernel), so the block shrinks as the client count grows:
+    first rows, down to one 8-row sublane tile, then lanes, halving down
+    to one 128-lane tile. A row block is a multiple of 8 or all ``rows``;
+    a lane block divides the row's ``LANES``."""
+    # double-buffered v, h, d in + d', x', h' out per client, plus u
+    per_elem = 2 * (6 * n_clients + 1) * itemsize
+    rb = TAIL_BLOCK_BUDGET // (per_elem * LANES) // 8 * 8
+    if rb >= 8:
+        return (rows if rows <= rb else rb), LANES
+    lb = LANES
+    while lb > 128 and per_elem * 8 * lb > TAIL_BLOCK_BUDGET:
+        lb //= 2
+    return min(rows, 8), lb
+
+
 def fedcet_round_tail_3d(v, h, d, u, scale, w, den, *, c: float,
                          alpha: float, beta: float, bits: int,
-                         interpret: bool = True):
+                         interpret: bool):
     """The fused shift:q8 -> weighted reduce -> FedCET pair round tail
     (oracle: ref.fedcet_round_tail) — ONE kernel visit per element: the
     quantizer codes, the reconstructed wire message and the client mean
@@ -119,20 +145,26 @@ def fedcet_round_tail_3d(v, h, d, u, scale, w, den, *, c: float,
 
     ``v``/``h``/``d``: [clients, rows, LANES]; ``u``: [rows, LANES];
     ``scale``: [rows, 1]; ``w``: [clients, 1]; ``den``: [1, 1]. The grid
-    tiles rows only — every client of a row block is resident so the
-    cross-client reduction happens in-kernel; the row block shrinks with
-    the client count to hold the ~6 resident [C, rb, LANES] f32 tiles
-    within the ~16 MiB VMEM budget."""
+    tiles rows and lanes (:func:`tail_blocks`); every client of a block
+    is resident so the cross-client reduction happens in-kernel."""
     n_clients, rows, _ = v.shape
-    # 6 live f32 tiles of [C, rb, LANES]: target <= ~2 MiB each.
-    rb = max(1, min(rows, 512 // max(1, n_clients)))
-    grid = (pl.cdiv(rows, rb),)
-    cs = pl.BlockSpec((n_clients, rb, LANES), lambda i: (0, i, 0))
-    rs = pl.BlockSpec((rb, LANES), lambda i: (i, 0))
-    ss = pl.BlockSpec((rb, 1), lambda i: (i, 0))
-    ws = pl.BlockSpec((n_clients, 1), lambda i: (0, 0))
-    ds = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    rb, lb = tail_blocks(n_clients, rows, v.dtype.itemsize)
+    grid = (pl.cdiv(rows, rb), LANES // lb)
+    cs = pl.BlockSpec((n_clients, rb, lb), lambda i, j: (0, i, j))
+    rs = pl.BlockSpec((rb, lb), lambda i, j: (i, j))
+    ss = pl.BlockSpec((rb, 1), lambda i, j: (i, 0))
+    ws = pl.BlockSpec((n_clients, 1), lambda i, j: (0, 0))
+    ds = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     sds = jax.ShapeDtypeStruct(v.shape, v.dtype)
+    # streams + ~6 [C, rb, lb] body temporaries; above the default scoped
+    # limit (several hundred clients) ask Mosaic for what the step needs.
+    need = ((2 * (6 * n_clients + 1) + 6 * n_clients) * rb * lb
+            * v.dtype.itemsize)
+    params = None
+    if need > DEFAULT_SCOPED_VMEM:
+        from jax.experimental.pallas import tpu as pltpu
+
+        params = pltpu.CompilerParams(vmem_limit_bytes=need + 2**20)
     return pl.pallas_call(
         functools.partial(_round_tail_kernel, c=c, alpha=alpha, beta=beta,
                           levels=2 ** (bits - 1) - 1),
@@ -140,12 +172,13 @@ def fedcet_round_tail_3d(v, h, d, u, scale, w, den, *, c: float,
         in_specs=[cs, cs, cs, rs, ss, ws, ds],
         out_specs=[cs, cs, cs],
         out_shape=[sds, sds, sds],
+        compiler_params=params,
         interpret=interpret,
     )(v, h, d, u, scale, w, den)
 
 
 def fedcet_comm_2d(d, v, v_bar, *, c: float, alpha: float,
-                   interpret: bool = True):
+                   interpret: bool):
     """Fused aggregation update; all operands [rows, LANES]."""
     rows = d.shape[0]
     rb = min(ROW_BLOCK, rows)

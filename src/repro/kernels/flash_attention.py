@@ -84,7 +84,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
                     chunk: int = 0, q_blk: int = 256, kv_blk: int = 256,
-                    interpret: bool = True):
+                    interpret: bool):
     """q: [B, S, Hq, D]; k/v: [B, T, Hkv, D]. Returns [B, S, Hq, D]."""
     B, S, Hq, D = q.shape
     T, Hkv = k.shape[1], k.shape[2]
@@ -98,9 +98,13 @@ def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
     qg = q.reshape(B, S, Hkv, G, D)
     if pad_q:
         qg = jnp.pad(qg, ((0, 0), (0, pad_q), (0, 0), (0, 0), (0, 0)))
+    # [B, Hkv, T, D]: the K/V block's last two dims are then (kv_blk, D)
+    # — Mosaic tiles the last two dims, and a squeezed Hkv there is refused.
+    k = jnp.swapaxes(k, 1, 2)
+    v = jnp.swapaxes(v, 1, 2)
     if pad_k:
-        k = jnp.pad(k, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
 
     kernel = functools.partial(
         _flash_kernel, kind=kind, window=window, chunk=chunk, q_blk=q_blk,
@@ -113,10 +117,10 @@ def flash_attention(q, k, v, *, kind: str = "causal", window: int = 0,
         in_specs=[
             pl.BlockSpec((None, q_blk, None, G, D),
                          lambda b, h, iq, ik: (b, iq, h, 0, 0)),
-            pl.BlockSpec((None, kv_blk, None, D),
-                         lambda b, h, iq, ik: (b, ik, h, 0)),
-            pl.BlockSpec((None, kv_blk, None, D),
-                         lambda b, h, iq, ik: (b, ik, h, 0)),
+            pl.BlockSpec((None, None, kv_blk, D),
+                         lambda b, h, iq, ik: (b, h, ik, 0)),
+            pl.BlockSpec((None, None, kv_blk, D),
+                         lambda b, h, iq, ik: (b, h, ik, 0)),
         ],
         out_specs=pl.BlockSpec((None, q_blk, None, G, D),
                                lambda b, h, iq, ik: (b, iq, h, 0, 0)),

@@ -45,7 +45,7 @@ def _seg_reduce_kernel(v_ref, o_ref, *, slots: int):
 
 
 def segment_reduce_2d(vals, *, slots: int, node_block: int = NODE_BLOCK,
-                      interpret: bool = True):
+                      interpret: bool):
     """Fixed-slot segment sum: ``vals`` is ``[n * slots, d]`` (row
     ``i * slots + s`` = node i's slot-s contribution; pre-padded by
     ops.py so ``n % node_block == 0`` and ``d % lane block == 0``);

@@ -46,7 +46,7 @@ def _stoch_quant_kernel(a_ref, u_ref, s_ref, o_ref, *, levels: int):
     o_ref[...] = q * s
 
 
-def stochastic_quantize_2d(a, u, s, *, bits: int, interpret: bool = True):
+def stochastic_quantize_2d(a, u, s, *, bits: int, interpret: bool):
     """Dithered quantize round-trip; a, u: [rows, LANES] (pre-tiled by
     ops.py), ``s``: the per-leaf scale as a (1, 1) array — every grid step
     maps the same single block (a scalar load per tile)."""
@@ -75,7 +75,7 @@ def _stoch_quant_rows_kernel(a_ref, u_ref, s_ref, o_ref, *, levels: int):
 
 
 def stochastic_quantize_rows_2d(a, u, s, *, bits: int,
-                                interpret: bool = True):
+                                interpret: bool):
     """Row-wise-scale variant for the packed parameter arena: the whole
     pytree is quantized in ONE kernel launch, with each 1024-lane row
     carrying its own leaf's scale — ``s`` is [rows, 1] (the per-leaf

@@ -43,7 +43,7 @@ def _ssd_intra_kernel(x_ref, dt_ref, acs_ref, b_ref, c_ref, o_ref):
     o_ref[...] = y.astype(o_ref.dtype)
 
 
-def ssd_intra(x, dt, a_cs, Bm, Cm, *, interpret: bool = True):
+def ssd_intra(x, dt, a_cs, Bm, Cm, *, interpret: bool):
     """x: [B, Nc, Lc, H, P]; dt/a_cs: [B, Nc, Lc, H]; Bm/Cm: [B, Nc, Lc, N].
     Returns y_intra [B, Nc, Lc, H, P] (f32 accumulated, cast to x.dtype)."""
     Bsz, Nc, Lc, H, P = x.shape

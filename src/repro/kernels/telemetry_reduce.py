@@ -78,7 +78,7 @@ def _sketch_kernel(x_ref, sq_ref, h_ref, *, bins: int, lo: float, hi: float,
 
 
 def client_sketch_2d(x, *, bins: int, lo: float, hi: float, n_valid: int,
-                     client_block: int = CLIENT_BLOCK, interpret: bool = True):
+                     client_block: int = CLIENT_BLOCK, interpret: bool):
     """Fused per-client square-norm + log-histogram over the flattened
     store ``x`` ``[n, d]`` (pre-padded by ops.py: ``n % client_block == 0``,
     ``d`` a lane-block multiple, pad entries zero). Returns

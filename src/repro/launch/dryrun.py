@@ -58,7 +58,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool,
     if shape.kind == "train":
         from repro.launch.train import lower_train_step, make_plan
 
-        plan = make_plan(arch, mesh, shape_name=shape_name)
+        plan = make_plan(arch, mesh, shape=shape_name)
         # donation off: CPU memory_analysis double-counts aliased carries,
         # and the dry-run's recorded numbers predate donation.
         lowered = lower_train_step(plan, donate=False)

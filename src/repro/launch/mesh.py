@@ -15,16 +15,7 @@ from __future__ import annotations
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed in jax 0.5; older jax defaults every axis to Auto
-    from jax.sharding import AxisType
-
-    def _auto_axis_types(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # pragma: no cover - depends on installed jax
-    def _auto_axis_types(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -37,14 +28,14 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"need {n} devices for mesh {shape}, have {len(devices)}; run "
             "under XLA_FLAGS=--xla_force_host_platform_device_count=512")
     dev = np.asarray(devices[:n]).reshape(shape)
-    return Mesh(dev, axes, **_auto_axis_types(len(axes)))
+    return Mesh(dev, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> Mesh:
     """Small mesh for sharding unit tests (subprocesses with 4-8 fake devs)."""
     n = int(np.prod(shape))
     dev = np.asarray(jax.devices()[:n]).reshape(shape)
-    return Mesh(dev, axes, **_auto_axis_types(len(axes)))
+    return Mesh(dev, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def client_axes(mesh: Mesh) -> tuple[str, ...]:

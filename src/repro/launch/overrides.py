@@ -23,9 +23,7 @@ from __future__ import annotations
 import dataclasses
 
 import numpy as np
-from jax.sharding import Mesh
-
-from repro.launch.mesh import _auto_axis_types
+from jax.sharding import AxisType, Mesh
 
 
 @dataclasses.dataclass(frozen=True)
@@ -60,4 +58,5 @@ def train_mesh_view(mesh: Mesh, fsdp: int) -> Mesh:
             new_shape.append(mesh.shape[n])
             new_names.append(n)
     dev = np.asarray(mesh.devices).reshape(new_shape)
-    return Mesh(dev, tuple(new_names), **_auto_axis_types(len(new_names)))
+    return Mesh(dev, tuple(new_names),
+                axis_types=(AxisType.Auto,) * len(new_names))

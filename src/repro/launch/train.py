@@ -20,6 +20,8 @@ Run as a script for a production-launch entry point:
 from __future__ import annotations
 
 import dataclasses
+import os
+from pathlib import Path
 from typing import Any, Callable
 
 import jax
@@ -27,7 +29,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config
-from repro.configs.base import INPUT_SHAPES, ArchConfig, FedScenario
+from repro.configs.base import (INPUT_SHAPES, ArchConfig, FedScenario,
+                                ShapeConfig)
 from repro.core.engine import EngineState, make_round_runner, scan_segments
 from repro.core.fedcet import FedCET, FedCETState
 from repro.core.staleness import DelayState
@@ -53,14 +56,16 @@ class TrainPlan:
         return client_axes(self.mesh)
 
 
-def make_plan(arch: str, mesh, *, shape_name: str = "train_4k",
+def make_plan(arch: str, mesh, *, shape: str | ShapeConfig = "train_4k",
               tau: int = 2, alpha: float = 1e-3, c: float = 0.05,
               dtype: str = "bfloat16",
               scenario: FedScenario | None = None) -> TrainPlan:
+    """``shape`` names an ``INPUT_SHAPES`` entry or is a ``ShapeConfig``
+    (its global batch splits evenly over the mesh's clients)."""
     from repro.launch.overrides import distribution_for, train_mesh_view
 
     cfg = get_config(arch).with_dtype(dtype)
-    shp = INPUT_SHAPES[shape_name]
+    shp = INPUT_SHAPES[shape] if isinstance(shape, str) else shape
     dist = distribution_for(arch)
     mesh = train_mesh_view(mesh, dist.fsdp)  # may split data -> (data, fsdp)
     nc = n_clients(mesh)
@@ -288,14 +293,21 @@ def run_training(arch: str, *, steps: int = 100, tau: int = 2,
         toks = ds.sample_round(r, tau)  # [tau, C, B, S]
         return {"tokens": toks}
 
-    state = algo.init(grad_fn, params, jax.tree.map(lambda b: b[0], batches_for(0)))
+    # jitted: run op by op, the init keeps its warm-up gradient's
+    # intermediates alive — at published widths that, not the round, set
+    # the peak device memory of a one-chip run.
+    state = jax.jit(lambda p, b: algo.init(grad_fn, p, b))(
+        params, jax.tree.map(lambda b: b[0], batches_for(0)))
 
-    # per-round mean client loss ON-DEVICE inside the scan (same expression
-    # the old boundary-only eval computed on the segment's last round, so
-    # logged history values are unchanged).
+    # per-round mean client loss ON-DEVICE inside the scan, on tokens no
+    # step has trained on yet: the clients' parameters ENTERING the round,
+    # on its last local batch (round 0's first batch fed the warm-up). A
+    # loss read after the round on a batch it stepped on is an in-sample
+    # loss: at published widths one step lowers its own batch's loss by
+    # ~1.5 nats while unseen tokens gain ~0.01.
     def round_loss(s, b):
-        b0 = jax.tree.map(lambda a: a[0], b)
-        return jnp.mean(jax.vmap(model.loss)(algo.client_params(s), b0))
+        bl = jax.tree.map(lambda a: a[-1], b)
+        return jnp.mean(jax.vmap(model.loss)(algo.client_params(s), bl))
 
     # the shared multi-round scan driver: rounds between log/checkpoint
     # boundaries run as one jitted lax.scan segment. The carry is donated
@@ -303,7 +315,8 @@ def run_training(arch: str, *, steps: int = 100, tau: int = 2,
     # place — the loop below rebinds `state` each call, never reusing the
     # donated buffers.
     runner = make_round_runner(algo, grad_fn, metric_fn=round_loss,
-                               metric_with_batch=True, donate=True)
+                               metric_with_batch=True, metric_before=True,
+                               donate=True)
 
     sinks = tele.parse_sinks(telemetry)
     tel_spec = getattr(algo, "telemetry", None)
@@ -400,7 +413,7 @@ def run_training(arch: str, *, steps: int = 100, tau: int = 2,
                 runner = make_round_runner(algo, grad_fn,
                                            metric_fn=round_loss,
                                            metric_with_batch=True,
-                                           donate=True)
+                                           metric_before=True, donate=True)
                 meter = dataclasses.replace(
                     CommMeter.for_params(params, algo=algo,
                                          n_clients=n_clients),
@@ -434,9 +447,23 @@ def run_training(arch: str, *, steps: int = 100, tau: int = 2,
     return history
 
 
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache where
+    ``JAX_COMPILATION_CACHE_DIR`` says (JAX reads the variable itself), or
+    else in ``.jax-cache/`` at the root of the checkout — a fixed path,
+    because the path is part of what a later run must find again. Call
+    before the first compile; returns the directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(Path(__file__).resolve().parents[3] / ".jax-cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def main(argv=None):
     import argparse
 
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=100)

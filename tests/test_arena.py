@@ -125,6 +125,7 @@ def test_layout_row_segments():
     lo = ArenaLayout.for_tree(tree)
     seg = lo.row_segments()
     assert seg.shape == (lo.rows,)
+    assert lo.rows % 8 == 0  # whole sublane tiles: the TPU layout stays tiled
     counts = np.bincount(seg, minlength=len(lo.shapes))
     assert tuple(counts) == lo.rows_per_leaf
     assert lo.num_params == sum(int(np.prod(s)) for s in lo.shapes)
@@ -279,10 +280,10 @@ def test_fedcet_comm_kernel_matches_ref_with_v():
         _assert_close(ker, ref)
 
 
-def test_round_tail_kernel_matches_ref():
+@pytest.mark.parametrize("c,rows", [(3, 5), (30, 13)])  # 30: lane tiles
+def test_round_tail_kernel_matches_ref(c, rows):
     from repro.kernels import ops as kops
 
-    c, rows = 3, 5
     ks = jax.random.split(jax.random.key(8), 5)
     v = jax.random.normal(ks[0], (c, rows, 1024))
     h = jax.random.normal(ks[1], (c, rows, 1024))

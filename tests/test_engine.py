@@ -40,6 +40,7 @@ from repro.core import (
     with_participation,
 )
 from repro.core.comm import topk_sparsify
+from repro.core.engine import make_round_runner
 from repro.core.lr_search import lr_search
 from repro.core.simulate import simulate_quadratic
 from repro.data.quadratic import make_quadratic_problem
@@ -151,6 +152,43 @@ def test_fedcet_tau1_and_tau4(problem):
         res = simulate_quadratic(algo, problem, rounds=10)
         np.testing.assert_allclose(np.asarray(res.errors),
                                    _errs(problem, traj), **_TOL)
+
+
+@pytest.mark.parametrize("repeat", [False, True])
+def test_round_runner_metric_before_reads_the_entering_state(problem,
+                                                             repeat):
+    """``metric_before=True`` evaluates the metric on the state ENTERING
+    each round: round r reads what the default runner reports after round
+    r - 1, and the final state is unchanged."""
+    alpha = lr_search(problem.mu, problem.L, TAU)
+    algo = FedCET(alpha=alpha, c=max_weight_c(problem.mu, alpha), tau=TAU,
+                  n_clients=problem.n_clients)
+    grad_fn = jax.grad(problem.client_loss)
+    batches = problem.stacked_batches(TAU)
+    state0 = algo.init(grad_fn, jnp.zeros((problem.dim,), problem.b.dtype),
+                       jax.tree.map(lambda b: b[0], batches))
+
+    def err(s, b):
+        return jnp.linalg.norm(algo.global_params(s) - problem.x_star)
+
+    def run(before):
+        runner = make_round_runner(algo, grad_fn, metric_fn=err,
+                                   metric_with_batch=True, repeat=repeat,
+                                   metric_before=before)
+        if repeat:
+            return runner(state0, batches, 5)
+        stacked = jax.tree.map(lambda a: jnp.stack([a] * 5), batches)
+        return runner(state0, stacked)
+
+    after_state, after = run(False)
+    before_state, before = run(True)
+    np.testing.assert_allclose(np.asarray(before[0]),
+                               np.asarray(err(state0, None)), **_TOL)
+    np.testing.assert_allclose(np.asarray(before[1:]),
+                               np.asarray(after[:-1]), **_TOL)
+    for got, want in zip(jax.tree.leaves(before_state),
+                         jax.tree.leaves(after_state)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), **_TOL)
 
 
 def test_fedcet_compressed_matches_seed(problem):
