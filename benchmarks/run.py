@@ -19,10 +19,9 @@ Prints ``name,us_per_call,derived`` CSV rows. Modules:
                      stale policy x compression (runs LAST: it enables x64)
   topology_sweep   — aggregation geometry: hierarchical exactness, NIDS
                      gossip rate vs spectral gap (also x64: keep last)
-  telemetry_bench  — in-trace telemetry overhead (<=10% asserted; full
-                     sketch stack <=1.15x) + the invariant- and rate-
-                     monitor staleness boundaries replayed live from one
-                     run's JSONL (also x64: keep last)
+  telemetry_bench  — the invariant- and rate-monitor staleness
+                     boundaries replayed live from one run's JSONL (also
+                     x64: keep last)
 
 After the module loop every ``results/BENCH_*.json`` merges into
 ``results/BENCH_trajectory.json`` — the one-file perf trajectory.

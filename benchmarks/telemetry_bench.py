@@ -1,19 +1,9 @@
-"""Benchmark: in-trace telemetry overhead + live monitor boundaries.
+"""Benchmark: live monitor boundaries of the in-trace telemetry.
 
-Three claims, all asserted:
+Two claims, both asserted (telemetry's device cost is measured on the chip
+by the chip benchmark's ``telemetry_ms``):
 
-1. **Overhead**: attaching ``with_telemetry`` to a composed FedCET round
-   (shift:q8 compression x fixed:2 delay) costs <= 10% wall-clock on the
-   paper's quadratic — the captures are a handful of fused reductions
-   riding the existing scan, with zero host syncs inside a segment. The
-   compiled footprint (optimized-HLO instruction count of the K-round
-   runner, off vs on) and the host-side drain cost are reported alongside.
-   With the FULL distribution-sketch stack on top (per-client norm
-   log-histograms + quantiles + top-k over every source, one O(N) pass
-   per round), the pin loosens to <= 1.15x — and the sketch-on state
-   stays a bitwise no-op.
-
-2. **Live invariant boundary**: the invariant monitor reproduces the
+1. **Live invariant boundary**: the invariant monitor reproduces the
    PR 3 pinned staleness boundary FROM A SINGLE RUN'S JSONL — no offline
    re-simulation: ``fixed:2`` + ``poly:1`` keeps uniform ages, so the
    streamed ``invariant_residual`` series stays at f64 noise and the
@@ -21,7 +11,7 @@ Three claims, all asserted:
    residual drifts above the 1e-6 bound, and the monitor emits WARN
    events naming the offending axis (stale_policy).
 
-3. **Live rate boundary**: the online linear-rate estimator
+2. **Live rate boundary**: the online linear-rate estimator
    (``RateMonitor``, windowed log-residual regression over the streamed
    ``err`` series) detects the SAME boundary as a rate break: ``fixed:2``
    + ``poly:1`` contracts linearly every round (rho_hat < 1, silent);
@@ -40,21 +30,13 @@ import json
 import os
 import tempfile
 
-from benchmarks._timing import min_of_batches, results_dir, write_bench_json
+from benchmarks._timing import results_dir, write_bench_json
 
-ROUNDS_PER_CALL = 32
 BOUNDARY_ROUNDS = 60
 N_CLIENTS = 32
 DIM = 512
-#: measurements per client — sets the local-step compute the captures
-#: amortize against (the paper's 10 makes the round so small that the
-#: handful of capture reductions shows up as >10%; any realistic local
-#: workload drowns them).
+#: measurements per client of the quadratic problem
 N_MEAS = 64
-MAX_OVERHEAD = 1.10
-#: full sketch stack (hist + quantiles + top-k over every source) — one
-#: O(N) pass over the whole client store per round rides on top.
-MAX_SKETCH_OVERHEAD = 1.15
 
 
 def _fedcet(problem, tau=2):
@@ -66,46 +48,12 @@ def _fedcet(problem, tau=2):
                   n_clients=problem.n_clients)
 
 
-def _runner_and_state(algo, problem):
-    import jax
-    import jax.numpy as jnp
-
-    from repro.core.engine import make_round_runner
-
-    grad_fn = jax.grad(problem.client_loss)
-    batches = problem.stacked_batches(algo.tau)
-    x0 = jnp.zeros((problem.dim,), dtype=problem.b.dtype)
-    state = algo.init(grad_fn, x0, jax.tree.map(lambda b: b[0], batches))
-    return make_round_runner(algo, grad_fn, repeat=True), state, batches
-
-
-def _time_round(algo, problem) -> tuple[float, object]:
-    import jax
-
-    runner, state, batches = _runner_and_state(algo, problem)
-    best, out = min_of_batches(
-        lambda: runner(state, batches, ROUNDS_PER_CALL), reps=3, batches=5)
-    jax.block_until_ready(out)
-    return best / ROUNDS_PER_CALL, out
-
-
-def _instr_count(algo, problem) -> int:
-    from repro.core.telemetry import instruction_count
-
-    # the runner is already a jitted callable (rounds static) — lower it
-    # directly rather than re-wrapping in jit.
-    runner, state, batches = _runner_and_state(algo, problem)
-    return instruction_count(runner.lower(state, batches, ROUNDS_PER_CALL))
-
-
 def _jsonl_boundary(base, problem, delay_spec: str, path: str):
     """One LIVE run: simulate with telemetry attached, drain the stacked
     series (plus the distance-to-optimum ``err`` series the rate
     estimator watches) into a JSONL sink, then read the FILE back and
     return the parsed residual series + WARN events split by monitor kind
     (what a dashboard would see)."""
-    import time
-
     import numpy as np
 
     from repro.core import (INVARIANT_MONITOR, JsonlSink, RateMonitor, drain,
@@ -116,7 +64,6 @@ def _jsonl_boundary(base, problem, delay_spec: str, path: str):
     algo = with_telemetry(
         with_delay(base, delay_spec, policy="poly:1"), True)
     monitors = (INVARIANT_MONITOR, RateMonitor(axis=rate_axis(algo)))
-    t0 = time.perf_counter()
     res = simulate_quadratic(algo, problem, rounds=BOUNDARY_ROUNDS)
     sink = JsonlSink(path)
     sink.emit(run_manifest(algo, n_params=problem.dim,
@@ -126,7 +73,6 @@ def _jsonl_boundary(base, problem, delay_spec: str, path: str):
     drain({**res.telemetry, "err": np.asarray(res.errors)[1:]},
           sinks=[sink], monitors=monitors, algo=algo, n_params=problem.dim)
     sink.close()
-    drain_us = (time.perf_counter() - t0) * 1e6 / BOUNDARY_ROUNDS
     with open(path) as f:
         events = [json.loads(line) for line in f]
     assert events[0]["event"] == "manifest", events[0]
@@ -137,7 +83,7 @@ def _jsonl_boundary(base, problem, delay_spec: str, path: str):
     inv_warns = [w for w in warns if w.get("kind") != "rate_break"]
     rate_warns = [w for w in warns if w.get("kind") == "rate_break"]
     assert len(residuals) == BOUNDARY_ROUNDS
-    return residuals, inv_warns, rate_warns, drain_us
+    return residuals, inv_warns, rate_warns
 
 
 def run(csv_rows=None, quick: bool = False):
@@ -145,56 +91,19 @@ def run(csv_rows=None, quick: bool = False):
 
     jax.config.update("jax_enable_x64", True)  # residual floor is f64 noise
 
-    from repro.core import with_compression, with_delay, with_telemetry
     from repro.data.quadratic import make_quadratic_problem
 
     problem = make_quadratic_problem(0, n_clients=N_CLIENTS, dim=DIM,
                                      n_measurements=N_MEAS)
     base = _fedcet(problem)
-    composed = with_delay(
-        with_compression(base, compressor="shift:q8"), "fixed:2",
-        policy="last")
 
-    # ---- 1. wall-clock overhead of the in-trace captures -----------------
-    off_us, out_off = _time_round(composed, problem)
-    on_us, out_on = _time_round(with_telemetry(composed, True), problem)
-    ratio = on_us / off_us
-    # telemetry must also be a bitwise no-op on the state it observed
-    s_off, s_on = out_off[0], out_on[0]
-    diffs = jax.tree.map(lambda a, b: float(abs(a - b).max()),
-                         jax.tree.leaves(s_off), jax.tree.leaves(s_on))
-    assert max(diffs) == 0.0, diffs
-    assert ratio <= MAX_OVERHEAD, (
-        f"telemetry overhead {ratio:.3f}x exceeds {MAX_OVERHEAD}x "
-        f"({off_us:.1f}us -> {on_us:.1f}us per round)")
-
-    # full distribution-sketch stack on top: hist + quantiles + top-k per
-    # source, one O(N) pass over the whole client store each round.
-    from repro.core import Telemetry
-
-    sketch_spec = Telemetry(sketches="auto", topk=4)
-    sketch_us, out_sk = _time_round(with_telemetry(composed, sketch_spec),
-                                    problem)
-    sketch_ratio = sketch_us / off_us
-    s_sk = out_sk[0]
-    diffs = jax.tree.map(lambda a, b: float(abs(a - b).max()),
-                         jax.tree.leaves(s_off), jax.tree.leaves(s_sk))
-    assert max(diffs) == 0.0, diffs
-    assert sketch_ratio <= MAX_SKETCH_OVERHEAD, (
-        f"sketch overhead {sketch_ratio:.3f}x exceeds {MAX_SKETCH_OVERHEAD}x "
-        f"({off_us:.1f}us -> {sketch_us:.1f}us per round)")
-
-    instr_off = _instr_count(composed, problem)
-    instr_on = _instr_count(with_telemetry(composed, True), problem)
-    instr_sk = _instr_count(with_telemetry(composed, sketch_spec), problem)
-
-    # ---- 2+3. the PR 3 staleness boundary, live from one run's JSONL -----
+    # ---- the staleness boundary, live from one run's JSONL --------------
     tmp = tempfile.mkdtemp(prefix="telemetry_bench_")
     exact_path = os.path.join(tmp, "fixed2_poly1.jsonl")
     drift_path = os.path.join(tmp, "rr2_poly1.jsonl")
-    exact, exact_warns, exact_rate, drain_exact_us = _jsonl_boundary(
+    exact, exact_warns, exact_rate = _jsonl_boundary(
         base, problem, "fixed:2", exact_path)
-    drift, drift_warns, drift_rate, drain_drift_us = _jsonl_boundary(
+    drift, drift_warns, drift_rate = _jsonl_boundary(
         base, problem, "rr:2", drift_path)
     # fixed:k -> uniform ages -> poly weights uniform -> exact: the monitor
     # stays silent and the streamed residual series sits at f64 noise.
@@ -222,50 +131,29 @@ def run(csv_rows=None, quick: bool = False):
     assert replayed[0]["round"] == drift_rate[0]["round"], (
         replayed[0], drift_rate[0])
 
-    timings = {
-        "round_telemetry_off": off_us,
-        "round_telemetry_on": on_us,
-        "round_sketch_on": sketch_us,
-        "drain_per_round_exact": drain_exact_us,
-        "drain_per_round_drift": drain_drift_us,
-    }
     write_bench_json(
         "telemetry",
         config={"n_clients": N_CLIENTS, "dim": DIM,
                 "n_measurements": N_MEAS,
-                "rounds_per_call": ROUNDS_PER_CALL,
-                "boundary_rounds": BOUNDARY_ROUNDS,
-                "scenario": "shift:q8 + fixed:2/last",
-                "max_overhead": MAX_OVERHEAD,
-                "max_sketch_overhead": MAX_SKETCH_OVERHEAD},
-        timings=timings,
-        extra={"overhead_ratio": round(ratio, 4),
-               "sketch_overhead_ratio": round(sketch_ratio, 4),
-               "hlo_instructions": {"off": instr_off, "on": instr_on,
-                                    "sketches": instr_sk},
-               "boundary": {
-                   "fixed2_poly1_max_residual": max(exact),
-                   "rr2_poly1_max_residual": max(drift),
-                   "rr2_poly1_warns": len(drift_warns),
-                   "fixed2_poly1_rate_breaks": len(exact_rate),
-                   "rr2_poly1_rate_breaks": len(drift_rate),
-                   "rr2_poly1_break_round": drift_rate[0]["round"],
-                   "rr2_poly1_break_rho_hat": drift_rate[0]["rho_hat"]}},
+                "boundary_rounds": BOUNDARY_ROUNDS},
+        timings={},
+        extra={"boundary": {
+            "fixed2_poly1_max_residual": max(exact),
+            "rr2_poly1_max_residual": max(drift),
+            "rr2_poly1_warns": len(drift_warns),
+            "fixed2_poly1_rate_breaks": len(exact_rate),
+            "rr2_poly1_rate_breaks": len(drift_rate),
+            "rr2_poly1_break_round": drift_rate[0]["round"],
+            "rr2_poly1_break_rho_hat": drift_rate[0]["rho_hat"]}},
         out_dir=results_dir())
     if csv_rows is not None:
-        csv_rows.append((
-            "telemetry/overhead", on_us,
-            f"off_us={off_us:.1f};ratio={ratio:.3f}"
-            f";sketch_ratio={sketch_ratio:.3f}"
-            f";hlo_off={instr_off};hlo_on={instr_on};hlo_sk={instr_sk}"))
         csv_rows.append((
             "telemetry/boundary", 0.0,
             f"fixed2_poly1_max_res={max(exact):.3e}"
             f";rr2_poly1_max_res={max(drift):.3e}"
             f";warns={len(drift_warns)}"
             f";rate_breaks={len(drift_rate)}"))
-    return {"ratio": ratio, "sketch_ratio": sketch_ratio,
-            "exact": max(exact), "drift": max(drift),
+    return {"exact": max(exact), "drift": max(drift),
             "rate_breaks": len(drift_rate)}
 
 

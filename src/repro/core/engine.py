@@ -723,7 +723,12 @@ class RoundEngine:
 
     # ------------------------------------------------------------- plumbing
     def _grad(self, grad_fn: GradFn) -> GradFn:
-        gf = vmap_grads(grad_fn, spmd_axis_name=(self.spmd_client_axes or None))
+        vg = vmap_grads(grad_fn, spmd_axis_name=(self.spmd_client_axes or None))
+
+        def gf(x, batch):
+            with tele.scope("fed.client_grad"):
+                return vg(x, batch)
+
         if self.arena:
             from repro.core.arena import Arena, pack, unpack
 
@@ -742,7 +747,10 @@ class RoundEngine:
             def arena_gf(x, batch):
                 if not isinstance(x, Arena):
                     return base(x, batch)
-                return pack(base(unpack(x), batch), x.layout)
+                with tele.scope("fed.client_grad"):
+                    g = vg(unpack(x), batch)
+                with tele.scope("fed.arena_pack"):
+                    return pack(g, x.layout)
 
             gf = arena_gf
         if self.telemetry is None:
@@ -756,10 +764,17 @@ class RoundEngine:
         def recording_gf(x, batch):
             g = inner_gf(x, batch)
             if tele.collecting():
-                tele.capture("grad_norm", tele.mean_client_norm(g))
+                with tele.scope("fed.telemetry"):
+                    tele.capture("grad_norm", tele.mean_client_norm(g))
             return g
 
         return recording_gf
+
+    def _local_update(self, gf, state, batch, rctx):
+        """The spec's ``local_step`` under the ``fed.local_update`` scope
+        (its gradient call keeps its own inner scope)."""
+        with tele.scope("fed.local_update"):
+            return self.local_step(gf, state, batch, rctx)
 
     def _msg_shapes(self, gf, inner, init_batch):
         """Abstract (eval_shape) wire-message tree of the current state —
@@ -798,70 +813,83 @@ class RoundEngine:
         shift) reverts to its pre-round value. Returns
         ``(inner, extras, dstate, tx)`` — ``tx`` is the post-transform
         wire message (``init`` seeds the buffer from it)."""
-        msg, mctx = self.message(gf, inner, batch, rctx)
+        with tele.scope("fed.local_update"):
+            msg, mctx = self.message(gf, inner, batch, rctx)
         # observer-only telemetry: rec is False when telemetry is detached
         # (bitwise no-op) or no tape is active (init / direct round calls).
         rec = self.telemetry is not None and tele.collecting()
         if rec:
-            tele.capture("msg_norm", tele.mean_client_norm(msg))
-            if self.telemetry.leaf_stats:
-                tele.capture("leaf_msg_norm", tele.leaf_client_norms(msg))
+            with tele.scope("fed.telemetry"):
+                tele.capture("msg_norm", tele.mean_client_norm(msg))
+                if self.telemetry.leaf_stats:
+                    tele.capture("leaf_msg_norm",
+                                 tele.leaf_client_norms(msg))
         if (dstate is None and self.delay is None and self.topology is None
                 and self.arena):
-            fused = self._fused_tail(inner, msg, mctx, extras, step, mask)
+            with tele.scope("fed.aggregate"):
+                fused = self._fused_tail(inner, msg, mctx, extras, step, mask)
             if fused is not None:
                 inner, new_extras = fused
                 return inner, tuple(new_extras), tstate, None, None
         raw = msg
         new_extras = []
-        for t, e in zip(self.transforms, extras):
-            msg, e = t.apply(msg, e, step)
-            new_extras.append(e)
+        with tele.scope("fed.uplink"):
+            for t, e in zip(self.transforms, extras):
+                msg, e = t.apply(msg, e, step)
+                new_extras.append(e)
         if rec and self.transforms:
-            diff = jax.tree.map(lambda a, b: a - b, msg, raw)
-            tele.capture("compress_err", tele.mean_client_norm(diff))
-            if self.telemetry.wants_sketch("compress_err"):
-                tele.capture("compress_err_clients",
-                             jnp.sqrt(tele.client_sq_norms(diff)))
-            if self.telemetry.leaf_stats:
-                tele.capture("leaf_compress_err",
-                             tele.leaf_client_norms(diff))
+            with tele.scope("fed.telemetry"):
+                diff = jax.tree.map(lambda a, b: a - b, msg, raw)
+                tele.capture("compress_err", tele.mean_client_norm(diff))
+                if self.telemetry.wants_sketch("compress_err"):
+                    tele.capture("compress_err_clients",
+                                 jnp.sqrt(tele.client_sq_norms(diff)))
+                if self.telemetry.leaf_stats:
+                    tele.capture("leaf_compress_err",
+                                 tele.leaf_client_norms(diff))
 
         if dstate is None:  # synchronous path (and always: init)
-            if self.topology is not None:
-                msg_bar, tstate_next = self.topology.reduce_and_advance(
-                    msg, self._topo_weights(mask), tstate)
-            else:
-                msg_bar, tstate_next = agg(msg), None
-            inner = self.server_aggregate(inner, msg, msg_bar, mctx, rctx)
+            with tele.scope("fed.aggregate"):
+                if self.topology is not None:
+                    msg_bar, tstate_next = self.topology.reduce_and_advance(
+                        msg, self._topo_weights(mask), tstate)
+                else:
+                    msg_bar, tstate_next = agg(msg), None
+                inner = self.server_aggregate(inner, msg, msg_bar, mctx,
+                                              rctx)
             return inner, tuple(new_extras), tstate_next, None, msg
 
         # fresh arrivals replace the buffered copy and reset its age; the
         # buffer is server state — it updates and ages every round.
-        buf = select_clients(msg, dstate.buf, fresh, self.n_clients)
-        age = jnp.where(fresh, 0, dstate.age + 1).astype(dstate.age.dtype)
-        if rec:
-            tele.capture("fresh_count", jnp.sum(fresh.astype(jnp.int32)))
-            tele.capture("age_min", jnp.min(age))
-            tele.capture("age_mean", jnp.mean(age.astype(jnp.float32)))
-            tele.capture("age_max", jnp.max(age))
-        w = self.delay.policy.weights(age, fresh)
-        # the stale policy's weights feed the TOPOLOGY's reduction (the
-        # same weighted seam as the synchronous path), so hierarchical /
-        # gossip aggregation composes with staleness with no extra code.
-        if self.topology is not None:
-            msg_bar, tstate_next = self.topology.reduce_and_advance(
-                buf, w, tstate)
-        else:
-            msg_bar, tstate_next = weighted_client_mean(buf, w), None
-        # each client's own-message slot is what the server attributed to
-        # it: the fresh wire message where it landed, the buffer elsewhere.
-        agg_inner = self.server_aggregate(inner, buf, msg_bar, mctx, rctx)
+        with tele.scope("fed.aggregate"):
+            buf = select_clients(msg, dstate.buf, fresh, self.n_clients)
+            age = jnp.where(fresh, 0, dstate.age + 1).astype(dstate.age.dtype)
+            if rec:
+                with tele.scope("fed.telemetry"):
+                    tele.capture("fresh_count",
+                                 jnp.sum(fresh.astype(jnp.int32)))
+                    tele.capture("age_min", jnp.min(age))
+                    tele.capture("age_mean",
+                                 jnp.mean(age.astype(jnp.float32)))
+                    tele.capture("age_max", jnp.max(age))
+            w = self.delay.policy.weights(age, fresh)
+            # the stale policy's weights feed the TOPOLOGY's reduction (the
+            # same weighted seam as the synchronous path), so hierarchical /
+            # gossip aggregation composes with staleness with no extra code.
+            if self.topology is not None:
+                msg_bar, tstate_next = self.topology.reduce_and_advance(
+                    buf, w, tstate)
+            else:
+                msg_bar, tstate_next = weighted_client_mean(buf, w), None
+            # each client's own-message slot is what the server attributed
+            # to it: the fresh wire message where it landed, the buffer
+            # elsewhere.
+            agg_inner = self.server_aggregate(inner, buf, msg_bar, mctx, rctx)
         if not self.delay.policy.apply_stale:
             # drop: no-arrival clients take the tau-th step as a pure local
             # step instead of the aggregation update (XLA CSEs the repeated
             # gradient evaluation at the same point).
-            local = self.local_step(gf, inner, batch, rctx)
+            local = self._local_update(gf, inner, batch, rctx)
             agg_inner = select_clients(agg_inner, local, fresh, self.n_clients)
         new_extras = tuple(
             select_clients(ne, e, fresh, self.n_clients)
@@ -986,9 +1014,11 @@ class RoundEngine:
             if mask is not None:
                 fresh = jnp.logical_and(fresh, mask)  # absent can't deliver
         if self.telemetry is not None and tele.collecting():
-            tele.capture("participating",
-                         jnp.sum(mask.astype(jnp.int32)) if mask is not None
-                         else jnp.asarray(self.n_clients, jnp.int32))
+            with tele.scope("fed.telemetry"):
+                tele.capture("participating",
+                             jnp.sum(mask.astype(jnp.int32))
+                             if mask is not None
+                             else jnp.asarray(self.n_clients, jnp.int32))
         frozen_inner, frozen_extras = inner, extras
 
         first_b = jax.tree.map(lambda b: b[0], batches)
@@ -998,7 +1028,7 @@ class RoundEngine:
             local_b = jax.tree.map(lambda b: b[: self.tau - 1], batches)
 
             def body(s, b):
-                return self.local_step(gf, s, b, rctx), None
+                return self._local_update(gf, s, b, rctx), None
 
             # muted: a capture inside the scan body would leak inner-scan
             # tracers onto the round-level telemetry tape.
@@ -1066,10 +1096,12 @@ class RoundEngine:
                 local_b = jax.tree.map(lambda b: b[: tau - 1], batches)
                 with tele.muted():
                     st, _ = jax.lax.scan(
-                        lambda s, b: (self.local_step(gf, s, b, rctx), None),
+                        lambda s, b: (self._local_update(gf, s, b, rctx),
+                                      None),
                         st, local_b)
             last_b = jax.tree.map(lambda b: b[tau - 1], batches)
-            msg, mctx = self.message(gf, st, last_b, rctx)
+            with tele.scope("fed.local_update"):
+                msg, mctx = self.message(gf, st, last_b, rctx)
             inner_c = gather_clients(st, idx, N)
             msg_c = gather_clients(msg, idx, N)
             mctx_c = gather_clients(mctx, idx, N)
@@ -1086,67 +1118,76 @@ class RoundEngine:
                 local_b = jax.tree.map(lambda b: b[: tau - 1], batches_c)
                 with tele.muted():
                     inner_c, _ = jax.lax.scan(
-                        lambda s, b: (self.local_step(gf, s, b, rctx_c),
+                        lambda s, b: (self._local_update(gf, s, b, rctx_c),
                                       None),
                         inner_c, local_b)
             last_b_c = jax.tree.map(lambda b: b[tau - 1], batches_c)
-            msg_c, mctx_c = self.message(gf, inner_c, last_b_c, rctx_c)
+            with tele.scope("fed.local_update"):
+                msg_c, mctx_c = self.message(gf, inner_c, last_b_c, rctx_c)
 
         # ---- phase B: transforms -> [buffer] -> reduce -> apply, all on
         # cohort-sized arrays in BOTH lowerings (shared code = bitwise
         # lowering equivalence; cross-client ops are per-cohort by design).
         rec = self.telemetry is not None and tele.collecting()
         if rec:
-            tele.capture("msg_norm", tele.mean_client_norm(msg_c))
-            tele.capture("participating",
-                         jnp.sum(mask.astype(jnp.int32)) if mask is not None
-                         else jnp.asarray(m, jnp.int32))
-            if self.telemetry.leaf_stats:
-                tele.capture("leaf_msg_norm", tele.leaf_client_norms(msg_c))
+            with tele.scope("fed.telemetry"):
+                tele.capture("msg_norm", tele.mean_client_norm(msg_c))
+                tele.capture("participating",
+                             jnp.sum(mask.astype(jnp.int32))
+                             if mask is not None
+                             else jnp.asarray(m, jnp.int32))
+                if self.telemetry.leaf_stats:
+                    tele.capture("leaf_msg_norm",
+                                 tele.leaf_client_norms(msg_c))
         tx_c = msg_c
         new_extras_c = []
-        for t, e in zip(self.transforms, extras_c):
-            tx_c, e = t.apply(tx_c, e, step0)
-            new_extras_c.append(e)
+        with tele.scope("fed.uplink"):
+            for t, e in zip(self.transforms, extras_c):
+                tx_c, e = t.apply(tx_c, e, step0)
+                new_extras_c.append(e)
         new_extras_c = tuple(new_extras_c)
         if rec and self.transforms:
-            diff_c = jax.tree.map(lambda a, b: a - b, tx_c, msg_c)
-            tele.capture("compress_err", tele.mean_client_norm(diff_c))
-            if self.telemetry.wants_sketch("compress_err"):
-                # cohort-sized wire data — finalize translates top-k slots
-                # to GLOBAL client ids through the captured cohort index.
-                tele.capture("compress_err_clients",
-                             jnp.sqrt(tele.client_sq_norms(diff_c)))
-                tele.capture("cohort_ids", idx.astype(jnp.int32))
-            if self.telemetry.leaf_stats:
-                tele.capture("leaf_compress_err",
-                             tele.leaf_client_norms(diff_c))
+            with tele.scope("fed.telemetry"):
+                diff_c = jax.tree.map(lambda a, b: a - b, tx_c, msg_c)
+                tele.capture("compress_err", tele.mean_client_norm(diff_c))
+                if self.telemetry.wants_sketch("compress_err"):
+                    # cohort-sized wire data — finalize translates top-k
+                    # slots to GLOBAL client ids through the captured
+                    # cohort index.
+                    tele.capture("compress_err_clients",
+                                 jnp.sqrt(tele.client_sq_norms(diff_c)))
+                    tele.capture("cohort_ids", idx.astype(jnp.int32))
+                if self.telemetry.leaf_stats:
+                    tele.capture("leaf_compress_err",
+                                 tele.leaf_client_norms(diff_c))
 
         if dstate is None:
-            if self.topology is not None:
-                msg_bar, tstate = self.topology.reduce_cohort_and_advance(
-                    tx_c, self._topo_weights(mask, m), idx, N, tstate)
-            else:
-                msg_bar = weighted_client_mean(
-                    tx_c, self._topo_weights(mask, m))
-            inner_c = self.server_aggregate(inner_c, tx_c, msg_bar,
-                                            mctx_c, rctx_c)
+            with tele.scope("fed.aggregate"):
+                if self.topology is not None:
+                    msg_bar, tstate = self.topology.reduce_cohort_and_advance(
+                        tx_c, self._topo_weights(mask, m), idx, N, tstate)
+                else:
+                    msg_bar = weighted_client_mean(
+                        tx_c, self._topo_weights(mask, m))
+                inner_c = self.server_aggregate(inner_c, tx_c, msg_bar,
+                                                mctx_c, rctx_c)
             dstate_next = None
         else:
-            buf_c = gather_clients(dstate.buf, idx, N)
-            buf_c = select_clients(tx_c, buf_c, fresh, m)
-            age_c = jnp.where(fresh, 0, dstate.age[idx] + 1
-                              ).astype(dstate.age.dtype)
-            w = self.delay.policy.weights(age_c, fresh)
-            if self.topology is not None:
-                msg_bar, tstate = self.topology.reduce_cohort_and_advance(
-                    buf_c, w, idx, N, tstate)
-            else:
-                msg_bar = weighted_client_mean(buf_c, w)
-            agg_inner_c = self.server_aggregate(inner_c, buf_c, msg_bar,
-                                                mctx_c, rctx_c)
+            with tele.scope("fed.aggregate"):
+                buf_c = gather_clients(dstate.buf, idx, N)
+                buf_c = select_clients(tx_c, buf_c, fresh, m)
+                age_c = jnp.where(fresh, 0, dstate.age[idx] + 1
+                                  ).astype(dstate.age.dtype)
+                w = self.delay.policy.weights(age_c, fresh)
+                if self.topology is not None:
+                    msg_bar, tstate = self.topology.reduce_cohort_and_advance(
+                        buf_c, w, idx, N, tstate)
+                else:
+                    msg_bar = weighted_client_mean(buf_c, w)
+                agg_inner_c = self.server_aggregate(inner_c, buf_c, msg_bar,
+                                                    mctx_c, rctx_c)
             if not self.delay.policy.apply_stale:
-                local = self.local_step(gf, inner_c, last_b_c, rctx_c)
+                local = self._local_update(gf, inner_c, last_b_c, rctx_c)
                 agg_inner_c = select_clients(agg_inner_c, local, fresh, m)
             inner_c = agg_inner_c
             new_extras_c = tuple(select_clients(ne, e, fresh, m)
@@ -1164,12 +1205,13 @@ class RoundEngine:
             if rec:
                 # cohort arrivals; ages summarize the FULL server buffer
                 # (non-cohort entries keep aging — the system-wide view).
-                tele.capture("fresh_count",
-                             jnp.sum(fresh.astype(jnp.int32)))
-                tele.capture("age_min", jnp.min(dstate_next.age))
-                tele.capture("age_mean",
-                             jnp.mean(dstate_next.age.astype(jnp.float32)))
-                tele.capture("age_max", jnp.max(dstate_next.age))
+                with tele.scope("fed.telemetry"):
+                    tele.capture("fresh_count",
+                                 jnp.sum(fresh.astype(jnp.int32)))
+                    tele.capture("age_min", jnp.min(dstate_next.age))
+                    tele.capture("age_mean", jnp.mean(
+                        dstate_next.age.astype(jnp.float32)))
+                    tele.capture("age_max", jnp.max(dstate_next.age))
 
         if mask is not None:
             # absent cohort members keep their pre-round rows entirely
@@ -1432,7 +1474,8 @@ def make_round_runner(algo, grad_fn: GradFn, *, metric_fn=None,
     def _metric(s, b):
         if metric_fn is None:
             return None
-        return metric_fn(s, b) if metric_with_batch else metric_fn(s)
+        with tele.scope("fed.round_loss"):
+            return metric_fn(s, b) if metric_with_batch else metric_fn(s)
 
     tel = getattr(algo, "telemetry", None)
 
@@ -1441,7 +1484,8 @@ def make_round_runner(algo, grad_fn: GradFn, *, metric_fn=None,
             return algo.round(grad_fn, s, b), None
         with tele.collect() as tape:
             s = algo.round(grad_fn, s, b)
-        return s, tel.finalize(tape, algo, s)
+        with tele.scope("fed.telemetry"):
+            return s, tel.finalize(tape, algo, s)
 
     def _step(s, b):
         m = _metric(s, b) if metric_before else None

@@ -57,10 +57,11 @@ tests/test_telemetry.py pins 0.0 divergence across the composed-scenario
 matrix.
 
 Profiling hooks live here too: :class:`TraceSession` brackets a
-``--trace-rounds a:b`` window with ``jax.profiler`` trace capture, and
-:func:`instruction_count` counts optimized-HLO instructions (reusing
-``roofline/hlo_parse``'s computation splitter) so benchmarks can report
-the instrumentation's compiled footprint.
+``--trace-rounds a:b`` window with ``jax.profiler`` trace capture;
+:func:`scope` names the round program's layers (:data:`SCOPES`) in the
+compiled program's ``op_name`` metadata, so a trace's device ops can be
+summed per layer; and :func:`drain` marks its host phases with
+``drain.*`` profiler spans.
 """
 
 from __future__ import annotations
@@ -824,52 +825,56 @@ def drain(series: dict | None, *, sinks=(), monitors=(), start_round: int = 0,
     events: list[dict] = []
     if not series:
         return events
-    host = {k: np.asarray(jax.device_get(v)) for k, v in series.items()}
+    span = jax.profiler.TraceAnnotation
+    with span("drain.device_get"):
+        host = {k: np.asarray(jax.device_get(v)) for k, v in series.items()}
     n = len(next(iter(host.values())))
     stat = dict(static or {})
     if algo is not None and n_params:
         from repro.core.comm import comm_bits_per_round
 
-        bits = comm_bits_per_round(algo, n_params,
-                                   getattr(algo, "n_clients", 1))
+        with span("drain.comm_bits"):
+            bits = comm_bits_per_round(algo, n_params,
+                                       getattr(algo, "n_clients", 1))
         stat.setdefault("bits_up", bits["up_bits"])
         stat.setdefault("bits_down", bits["down_bits"])
     leaf_keys = [k for k in host if k.startswith("leaf_")]
     observers = [m for m in monitors if hasattr(m, "observe")]
     checks = [m for m in monitors if not hasattr(m, "observe")]
-    for i in range(n):
-        ev = {"event": "round", "round": int(start_round + i)}
-        for k, v in host.items():
-            if k in leaf_keys:
-                continue
-            ev[k] = _jsonable(v[i])
-        ev.update(stat)
-        rate_warns = [w for w in (m.observe(ev) for m in observers) if w]
-        events.append(ev)
-        emit_event(sinks, ev)
-        if leaf_keys:
-            lev = {"event": "leaf_stats", "round": ev["round"]}
-            if leaf_names is not None and i == 0:
-                lev["names"] = list(leaf_names)
-            if leaf_bits is not None and i == 0:
-                lev["bits"] = [float(b) for b in leaf_bits]
-            for k in leaf_keys:
-                lev[k[len("leaf_"):]] = _jsonable(host[k][i])
-            events.append(lev)
-            emit_event(sinks, lev)
-        for m in checks:
-            v = ev.get(m.metric)
-            if (isinstance(v, (int, float)) and not isinstance(v, bool)
-                    and m.violated(v)):
-                warn = {"event": "monitor", "level": "WARN",
-                        "metric": m.metric, "round": ev["round"],
-                        "value": v, "bound": m.bound,
-                        "mode": m.mode, "axis": m.axis}
-                events.append(warn)
-                emit_event(sinks, warn)
-        for w in rate_warns:
-            events.append(w)
-            emit_event(sinks, w)
+    with span("drain.events"):
+        for i in range(n):
+            ev = {"event": "round", "round": int(start_round + i)}
+            for k, v in host.items():
+                if k in leaf_keys:
+                    continue
+                ev[k] = _jsonable(v[i])
+            ev.update(stat)
+            rate_warns = [w for w in (m.observe(ev) for m in observers) if w]
+            events.append(ev)
+            emit_event(sinks, ev)
+            if leaf_keys:
+                lev = {"event": "leaf_stats", "round": ev["round"]}
+                if leaf_names is not None and i == 0:
+                    lev["names"] = list(leaf_names)
+                if leaf_bits is not None and i == 0:
+                    lev["bits"] = [float(b) for b in leaf_bits]
+                for k in leaf_keys:
+                    lev[k[len("leaf_"):]] = _jsonable(host[k][i])
+                events.append(lev)
+                emit_event(sinks, lev)
+            for m in checks:
+                v = ev.get(m.metric)
+                if (isinstance(v, (int, float)) and not isinstance(v, bool)
+                        and m.violated(v)):
+                    warn = {"event": "monitor", "level": "WARN",
+                            "metric": m.metric, "round": ev["round"],
+                            "value": v, "bound": m.bound,
+                            "mode": m.mode, "axis": m.axis}
+                    events.append(warn)
+                    emit_event(sinks, warn)
+            for w in rate_warns:
+                events.append(w)
+                emit_event(sinks, w)
     return events
 
 
@@ -948,16 +953,19 @@ class TraceSession:
             self.active = False
 
 
-def instruction_count(lowered_or_text) -> int:
-    """Instruction count of an optimized HLO module (a ``jit(...).lower()``
-    result or its compiled text), via ``roofline/hlo_parse``'s computation
-    splitter — one count per "name = op(...)" line across all
-    computations. Benchmarks use it to report telemetry's compiled
-    footprint next to its wall-clock cost."""
-    txt = lowered_or_text
-    if not isinstance(txt, str):
-        txt = lowered_or_text.compile().as_text()
-    from repro.roofline.hlo_parse import _split_computations
+#: the round program's layers, as ``jax.named_scope`` names the engine
+#: places at its call sites (so every algorithm gets the same ones): the
+#: clients' vmapped forward/backward, the arena pack of its gradient, the
+#: spec's local update and message, the uplink transforms, the reduction
+#: and server update, telemetry captures and finalize, and the per-round
+#: loss. A scope changes only the compiled ops' ``op_name`` metadata; an
+#: op's innermost scope names its layer (a fusion takes its root's).
+SCOPES = ("fed.client_grad", "fed.arena_pack", "fed.local_update",
+          "fed.uplink", "fed.aggregate", "fed.telemetry", "fed.round_loss")
 
-    return sum(1 for lines in _split_computations(txt).values()
-               for ln in lines if " = " in ln)
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for one of :data:`SCOPES`."""
+    if name not in SCOPES:
+        raise ValueError(f"unknown scope {name!r}; have {SCOPES}")
+    return jax.named_scope(name)

@@ -265,8 +265,11 @@ def run_training(arch: str, *, steps: int = 100, tau: int = 2,
     ``trace_rounds`` (``"a:b"`` or ``"a"``) brackets that round window
     with a ``jax.profiler`` trace written under ``trace_dir`` — segment
     boundaries are forced at the window edges so the trace covers exactly
-    those rounds. Per-round stdout summary lines (round, loss, bits_up,
-    active_clients) print for every ``log_every``-th round."""
+    those rounds, each segment with its host work under the spans
+    ``segment_dispatch``, ``telemetry_drain`` (around ``drain``'s own
+    ``drain.*`` spans) and ``results_fetch``. Per-round stdout summary
+    lines (round, loss, bits_up, active_clients) print for every
+    ``log_every``-th round."""
     from repro.checkpoint.ckpt import save
     from repro.core import telemetry as tele
     from repro.core.comm import CommMeter, comm_bits_per_round, leaf_info_of
@@ -380,26 +383,28 @@ def run_training(arch: str, *, steps: int = 100, tau: int = 2,
                     "cohort": str(cohort), "arena": arena, "seed": seed},
             monitors=monitors, leaf_info=leaf_info))
     history = {"round": [], "loss": [], "comm_bytes": []}
+    # host spans on the profiler's clock, named as the chip benchmark names
+    # its own around the same calls.
+    span = jax.profiler.TraceAnnotation
     for r, stop in scan_segments(0, steps, is_stop):
         ev = trace.maybe_start(r)
         if ev:
             tele.emit_event(sinks, ev)
         per_round = [batches_for(i) for i in range(r, stop + 1)]
         stacked = jax.tree.map(lambda *bs: jnp.stack(bs), *per_round)
-        state, ys = runner(state, stacked)
-        losses, tel_series = tele.split_metrics(algo, ys)
-        ev = trace.maybe_stop(stop + 1)
-        if ev:
-            tele.emit_event(sinks, ev)
-        if tel_series is not None and sinks:
-            # the per-round loss rides the round events so the rate
-            # estimator / report can read the LM convergence curve.
-            tele.drain({**tel_series, "loss": losses}, sinks=sinks,
-                       monitors=monitors, start_round=r, algo=algo,
-                       n_params=meter.n_params, leaf_names=leaf_names,
-                       leaf_bits=meter.leaf_bits)
-        for _ in range(r, stop + 1):
-            meter.tick_round(algo)
+        with span("segment_dispatch"):
+            state, ys = runner(state, stacked)
+            losses, tel_series = tele.split_metrics(algo, ys)
+        with span("telemetry_drain"):
+            if tel_series is not None and sinks:
+                # the per-round loss rides the round events so the rate
+                # estimator / report can read the LM convergence curve.
+                tele.drain({**tel_series, "loss": losses}, sinks=sinks,
+                           monitors=monitors, start_round=r, algo=algo,
+                           n_params=meter.n_params, leaf_names=leaf_names,
+                           leaf_bits=meter.leaf_bits)
+            for _ in range(r, stop + 1):
+                meter.tick_round(algo)
         if adaptive is not None and tel_series is not None \
                 and "compress_err" in tel_series:
             new_plan = adaptive.update(
@@ -425,7 +430,11 @@ def run_training(arch: str, *, steps: int = 100, tau: int = 2,
                     tele.emit_event(sinks, {
                         "event": "plan_adapt", "round": stop,
                         "bits_per_round": per_round_bits["up_bits"]})
-        losses = jax.device_get(losses)
+        with span("results_fetch"):
+            losses = jax.device_get(losses)
+        ev = trace.maybe_stop(stop + 1)
+        if ev:
+            tele.emit_event(sinks, ev)
         active = None if tel_series is None else tel_series.get("participating")
         for i, rr in enumerate(range(r, stop + 1)):
             if rr % log_every == 0 or rr == steps - 1:
