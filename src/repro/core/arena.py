@@ -221,6 +221,24 @@ def unpack(arena: Arena):
     return jax.tree.unflatten(lo.treedef, out)
 
 
+def pin_layouts(tree):
+    """Every :class:`Arena` buffer of ``tree`` held in its default
+    row-major layout, the one the Pallas kernels read. Applied to the state
+    a round scan carries: left free, the TPU compiler may carry a
+    ``[clients, rows, LANES]`` buffer clients-minor across rounds and copy
+    it back and forth every round (two copies a round and 2.6 GB more
+    compiled peak on fedlm-100m with 4 clients and τ=2)."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    def pin(a):
+        if not isinstance(a, Arena):
+            return a
+        order = tuple(range(jnp.ndim(a.data)))
+        return Arena(with_layout_constraint(a.data, Layout(order)), a.layout)
+
+    return jax.tree.map(pin, tree, is_leaf=lambda a: isinstance(a, Arena))
+
+
 def adapt_state(src, like):
     """Structurally adapt a checkpointed engine state between the per-leaf
     and arena representations: wherever ``like`` carries an :class:`Arena`

@@ -1488,8 +1488,11 @@ def make_round_runner(algo, grad_fn: GradFn, *, metric_fn=None,
             return s, tel.finalize(tape, algo, s)
 
     def _step(s, b):
+        from repro.core.arena import pin_layouts
+
         m = _metric(s, b) if metric_before else None
         s, tl = _round(s, b)
+        s = pin_layouts(s)
         if not metric_before:
             m = _metric(s, b)
         return s, (m if tel is None else {"metric": m, "telemetry": tl})

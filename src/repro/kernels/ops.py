@@ -140,16 +140,13 @@ def telemetry_sketch(data, *, bins: int, lo: float, hi: float, k: int,
     from repro.kernels import telemetry_reduce as KT
 
     n = data.shape[0]
-    flat = data.reshape(n, -1)
     if _use_kernel(impl):
-        cb = min(KT.CLIENT_BLOCK, n)
-        db = min(KT.LANE_BLOCK, -(-flat.shape[1] // 128) * 128)
-        t = jnp.pad(flat, ((0, -n % cb), (0, -flat.shape[1] % db)))
-        sq, hist = KT.client_sketch_2d(t, bins=bins, lo=lo, hi=hi,
-                                       n_valid=n, interpret=_interpret())
-        sq, hist = sq[:n, 0], hist[0, :bins]
+        sq, hist = KT.client_sketch_3d(data, bins=bins, lo=lo, hi=hi,
+                                       interpret=_interpret())
+        sq, hist = sq[:, 0], hist[0, :bins]
     else:
-        sq, hist = R.client_sketch(flat, bins=bins, lo=lo, hi=hi)
+        sq, hist = R.client_sketch(data.reshape(n, -1), bins=bins, lo=lo,
+                                   hi=hi)
     norms = jnp.sqrt(sq)
     tv, ti = jax.lax.top_k(norms, min(k, n))
     return norms, hist, tv, ti.astype(jnp.int32)

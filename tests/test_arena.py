@@ -154,6 +154,30 @@ def test_arena_is_transparent_pytree():
     assert jax.tree.leaves(sds)[0].shape == a.data.shape
 
 
+def test_pin_layouts_keeps_values_and_structure():
+    """The layout pin a round scan applies to its carried state changes
+    nothing but the compiler's freedom: arena buffers (model-shaped and
+    stacked) keep their values and layout object, other leaves pass
+    through untouched, inside jit and a scan carry alike."""
+    from repro.core.arena import pin_layouts
+
+    tree = _odd_tree(jax.random.key(4))
+    lo = ArenaLayout.for_tree(tree)
+    state = {"x": pack(tree, lo),
+             "d": pack(_odd_tree(jax.random.key(5), lead=3), lo),
+             "step": jnp.int32(7), "w": jnp.ones((2, 5))}
+    pinned = jax.jit(pin_layouts)(state)
+    assert jax.tree.structure(pinned) == jax.tree.structure(state)
+    assert pinned["x"].layout is lo and pinned["d"].layout is lo
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(pinned)):
+        assert a.dtype == b.dtype and bool(jnp.all(a == b))
+    carried, _ = jax.lax.scan(
+        lambda s, _: (pin_layouts(jax.tree.map(lambda v: 2 * v, s)), None),
+        state, None, length=2)
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(carried)):
+        assert bool(jnp.all(4 * a == b))
+
+
 # ------------------------------------- arena == per-leaf, quadratic (f64)
 @pytest.mark.parametrize("name", list(_algos()))
 def test_arena_equiv_bare(name):

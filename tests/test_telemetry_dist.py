@@ -161,15 +161,25 @@ def test_cohort_and_dense_lowerings_sketch_identically():
 
 
 # --------------------------------------------------- kernel vs oracle
-@pytest.mark.parametrize("n_clients", [8, 13])
-def test_telemetry_reduce_kernel_matches_ref(n_clients):
+# (clients, rows): the f64 store's row block (kernels/telemetry_reduce.py:
+# row_block) is 256 rows at 2 clients, 128 at 4 and 64 at 8 or more
+@pytest.mark.parametrize("n_clients,rows", [
+    # every row in one block; 13: a ragged client block
+    pytest.param(8, 3, id="8"), pytest.param(13, 3, id="13"),
+    (2, 512), (4, 256),     # the cells' client counts, whole row blocks
+    (4, 300),               # a ragged last row block
+    (13, 70),               # two client blocks and a ragged last row block
+])
+def test_telemetry_reduce_kernel_matches_ref(n_clients, rows):
     """Pallas kernel (interpret mode) vs the jnp oracle on an arena-style
-    ``[N, rows, 1024]`` store with zero-pad tail entries and a client
-    count that does not divide the client block."""
+    ``[N, rows, 1024]`` store with zero-pad tail entries, over client and
+    row counts that do and do not divide the kernel's blocks."""
     rng = np.random.default_rng(0)
-    rows, lanes = 3, 1024
+    lanes = 1024
+    # norms span 1e-4 .. 5e3 at every row count, the range the absolute
+    # tolerances below were set for (1 ulp of the largest is 9e-13)
     data = rng.normal(size=(n_clients, rows, lanes)) \
-        * np.logspace(-6, 2, n_clients)[:, None, None]
+        * np.logspace(-6, 2, n_clients)[:, None, None] * np.sqrt(3 / rows)
     data[:, -1, 512:] = 0.0  # arena zero padding
     data = jnp.asarray(data)
     kw = dict(bins=48, lo=-12.0, hi=4.0, k=4)

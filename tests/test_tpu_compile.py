@@ -12,6 +12,8 @@ one process may load the TPU library, and under several test workers only
 the one given this file should.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -22,6 +24,7 @@ from repro.core.arena import LANES, ArenaLayout
 from repro.kernels import fedcet_update as KF
 from repro.kernels import flash_attention as KA
 from repro.kernels import gossip_reduce as KG
+from repro.kernels import ops
 from repro.kernels import quantize as KQ
 from repro.kernels import telemetry_reduce as KT
 from repro.models import build_model
@@ -78,6 +81,7 @@ def compile_for_chip(one_chip, no_compile_cache):
         with jax.enable_x64(False):
             text = jax.jit(fn).lower(*args).compile().as_text()
         assert "tpu_custom_call" in text  # the Mosaic kernel, not a fallback
+        return text
     return compile_
 
 
@@ -104,12 +108,30 @@ def test_quantize_rows_compiles(compile_for_chip, rows):
         ((rows, LANES), F32), ((rows, LANES), F32), ((rows, 1), F32))
 
 
-@pytest.mark.parametrize("clients", [3, 16])
+@pytest.mark.parametrize("clients", [2, 3, 4, 13, 16])
 def test_client_sketch_compiles(compile_for_chip, rows, clients):
     compile_for_chip(
-        lambda x: KT.client_sketch_2d(x, bins=48, lo=-12.0, hi=4.0,
-                                      n_valid=clients, interpret=False),
-        ((clients, rows * LANES), F32))
+        lambda x: KT.client_sketch_3d(x, bins=48, lo=-12.0, hi=4.0,
+                                      interpret=False),
+        ((clients, rows, LANES), F32))
+
+
+def test_client_sketch_reads_the_arena_in_place(compile_for_chip, rows,
+                                                monkeypatch):
+    """The wrapper hands the kernel the arena buffer in its own layout:
+    no relayout copy and no pad of the store feeds the custom call."""
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    sketch = ops.telemetry_sketch.__wrapped__  # traced here, not cached
+    text = compile_for_chip(
+        lambda x: sketch(x, bins=48, lo=-12.0, hi=4.0, k=4, impl="kernel"),
+        ((4, rows, LANES), F32))
+    call = next(ln for ln in text.splitlines() if "tpu_custom_call" in ln)
+    arg = re.search(r"custom-call\((%[\w.-]+)\)", call).group(1)
+    (defn,) = [ln for ln in text.splitlines()
+               if ln.strip().startswith(arg + " = ")]
+    assert "parameter(0)" in defn, defn
+    assert f"f32[4,{rows},1024]{{2,1,0:T(8,128)}}" in defn, defn
+    assert " copy(" not in text and " pad(" not in text
 
 
 def test_gossip_reduce_compiles(compile_for_chip, rows):
