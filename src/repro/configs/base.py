@@ -43,9 +43,19 @@ class ArchConfig:
     tie_embeddings: bool = False
     embed_scale: bool = False        # gemma: multiply embeddings by sqrt(d)
 
-    # moe
+    # granite's multipliers; at these defaults none adds an operation
+    embedding_multiplier: float = 1.0   # embeddings times this
+    residual_multiplier: float = 1.0    # each block branch times this
+    attention_multiplier: Optional[float] = None  # None: 1/sqrt(head_dim)
+    logits_scaling: float = 1.0         # logits divided by this
+
+    # moe: ``n_experts`` experts are held here, experts ``expert_offset`` ..
+    # ``expert_offset + n_experts - 1`` of the ``router_experts`` the router
+    # scores (None: all of them are held)
     n_experts: int = 0
     experts_per_token: int = 0
+    router_experts: Optional[int] = None
+    expert_offset: int = 0
     moe_shared_expert: bool = False
     capacity_factor: float = 1.25
 
@@ -79,6 +89,13 @@ class ArchConfig:
     def __post_init__(self):
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // max(self.n_heads, 1))
+        if self.router_experts is None:
+            object.__setattr__(self, "router_experts", self.n_experts)
+        if not 0 <= self.expert_offset <= self.router_experts - self.n_experts:
+            raise ValueError(
+                f"{self.name}: experts {self.expert_offset}.."
+                f"{self.expert_offset + self.n_experts - 1} are not among "
+                f"the router's {self.router_experts}")
         if self.n_heads and self.n_kv_heads:
             assert self.n_heads % self.n_kv_heads == 0, (self.n_heads, self.n_kv_heads)
 
@@ -102,10 +119,13 @@ class ArchConfig:
             window=min(self.window, 64),
             chunk=min(self.chunk, 64),
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
+            router_experts=(min(self.router_experts, 4)
+                            if self.router_experts else 0),
+            expert_offset=0,
             experts_per_token=(min(self.experts_per_token, 2)
                                if self.experts_per_token else 0),
-            # drop-free capacity so prefill/decode stay bit-consistent in the
-            # smoke tests (production configs keep the real 1.25 and drop).
+            # drop-free capacity for the mesh lowerings' capacity dispatch
+            # (production configs keep the real 1.25 and drop there).
             capacity_factor=float(max(self.n_experts, 1)),
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_headdim=16 if self.ssm_state else self.ssm_headdim,

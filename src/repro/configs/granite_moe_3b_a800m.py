@@ -1,5 +1,6 @@
-"""granite-moe-3b-a800m [moe] — 40 experts, top-8 routing
-[hf:ibm-granite/granite-3.0-1b-a400m-base]."""
+"""granite-moe-3b-a800m [moe] — 40 experts, top-8 routing, tied embeddings
+and Granite's four multipliers
+[hf:ibm-granite/granite-3.0-3b-a800m-base]."""
 
 from repro.configs.base import ArchConfig
 
@@ -16,5 +17,10 @@ CONFIG = ArchConfig(
     n_experts=40,
     experts_per_token=8,
     activation="swiglu",
-    citation="hf:ibm-granite/granite-3.0-1b-a400m-base",
+    tie_embeddings=True,
+    embedding_multiplier=12.0,
+    residual_multiplier=0.22,
+    attention_multiplier=0.015625,   # 1/64, not 1/sqrt(64)
+    logits_scaling=6.0,
+    citation="hf:ibm-granite/granite-3.0-3b-a800m-base",
 )

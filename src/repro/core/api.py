@@ -49,6 +49,20 @@ GradFn = Callable[[Any, Any], Any]  # (params, batch) -> grads, single client
 AlgState = Any
 
 
+@dataclasses.dataclass(frozen=True)
+class StatsGrad:
+    """A grad_fn of a loss that also returns per-call counters:
+    ``loss_and_stats(params, batch) -> (loss, {name: array})``. Called, it
+    gives ``(grads, counters)``; the engine keeps the gradient and passes
+    the counters to its telemetry (``repro.core.telemetry.count``), which
+    sums them over clients and local steps into the round's event."""
+
+    loss_and_stats: Callable
+
+    def __call__(self, params, batch):
+        return jax.grad(self.loss_and_stats, has_aux=True)(params, batch)
+
+
 @runtime_checkable
 class FederatedAlgorithm(Protocol):
     """Structural interface shared by FedCET and all baselines."""

@@ -142,7 +142,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import telemetry as tele
-from repro.core.api import GradFn, vmap_grads
+from repro.core.api import GradFn, StatsGrad, vmap_grads
 from repro.core.comm import sparsified_up_frac
 from repro.core.staleness import (
     DelayState,
@@ -724,6 +724,13 @@ class RoundEngine:
     # ------------------------------------------------------------- plumbing
     def _grad(self, grad_fn: GradFn) -> GradFn:
         vg = vmap_grads(grad_fn, spmd_axis_name=(self.spmd_client_axes or None))
+        if isinstance(grad_fn, StatsGrad):
+            vg_stats = vg
+
+            def vg(x, batch):
+                g, stats = vg_stats(x, batch)
+                tele.count({k: jnp.sum(v, axis=0) for k, v in stats.items()})
+                return g
 
         def gf(x, batch):
             with tele.scope("fed.client_grad"):
@@ -1026,14 +1033,22 @@ class RoundEngine:
 
         if self.tau > 1:
             local_b = jax.tree.map(lambda b: b[: self.tau - 1], batches)
+            counted = self.telemetry is not None and tele.collecting()
 
             def body(s, b):
-                return self._local_update(gf, s, b, rctx), None
+                if not counted:
+                    return self._local_update(gf, s, b, rctx), None
+                with tele.counting() as counts:
+                    s = self._local_update(gf, s, b, rctx)
+                return s, counts
 
             # muted: a capture inside the scan body would leak inner-scan
-            # tracers onto the round-level telemetry tape.
+            # tracers onto the round-level telemetry tape; the body's
+            # counters leave it as the scan's outputs instead.
             with tele.muted():
-                inner, _ = jax.lax.scan(body, inner, local_b)
+                inner, counts = jax.lax.scan(body, inner, local_b)
+            if counted:
+                tele.count(jax.tree.map(lambda a: jnp.sum(a, axis=0), counts))
 
         last_b = jax.tree.map(lambda b: b[self.tau - 1], batches)
         inner, extras, tstate, dstate, _ = self._comm_step(

@@ -84,6 +84,8 @@ import numpy as np
 #: traced state, so it adds no jaxpr inputs and costs nothing when empty.
 _TAPES: list[dict] = []
 _MUTE: int = 0
+#: open count regions (:func:`counting`), innermost last
+_COUNTS: list[dict] = []
 
 
 def collecting() -> bool:
@@ -111,6 +113,33 @@ def collect():
         yield tape
     finally:
         _TAPES.pop()
+
+
+def count(values: dict) -> None:
+    """Add per-call counters ``{name: array}`` to the innermost open
+    :func:`counting` region, else to the round's tape (summing with what
+    is there), else nowhere."""
+    if _COUNTS:
+        target = _COUNTS[-1]
+    elif collecting():
+        target = _TAPES[-1]
+    else:
+        return
+    for name, v in values.items():
+        target[name] = target[name] + v if name in target else v
+
+
+@contextlib.contextmanager
+def counting():
+    """Open a count region around one iteration of a muted ``lax.scan``
+    body; yields the dict of its summed counters, which the body returns
+    as the scan's outputs."""
+    counts: dict = {}
+    _COUNTS.append(counts)
+    try:
+        yield counts
+    finally:
+        _COUNTS.pop()
 
 
 @contextlib.contextmanager
@@ -452,6 +481,14 @@ class Telemetry:
         # raw per-client seam captures feed sketches only — never emitted.
         cohort_ids = out.pop("cohort_ids", None)
         err_clients = out.pop("compress_err_clients", None)
+        slots = out.pop("moe_expert_slots", None)
+        if slots is not None:
+            # routed slots computed by the held experts, over clients and
+            # steps; the most-loaded held expert's over the held mean
+            out["moe_held_slots"] = jnp.sum(slots)
+            mean = jnp.mean(slots.astype(jnp.float32))
+            out["moe_load_max"] = jnp.max(slots).astype(jnp.float32) / (
+                jnp.maximum(mean, 1.0))
         inner = algo._inner(state)
         d = getattr(inner, "d", None)
         if d is not None:

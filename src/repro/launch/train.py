@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
 from repro.configs.base import (INPUT_SHAPES, ArchConfig, FedScenario,
                                 ShapeConfig)
+from repro.core.api import StatsGrad
 from repro.core.engine import EngineState, make_round_runner, scan_segments
 from repro.core.fedcet import FedCET, FedCETState
 from repro.core.staleness import DelayState
@@ -290,7 +291,10 @@ def run_training(arch: str, *, steps: int = 100, tau: int = 2,
     algo = scenario.apply(FedCET(alpha=alpha, c=c, tau=tau, n_clients=n_clients))
     ds = make_hetero_lm_dataset(cfg.vocab_size, n_clients, seq_len, batch,
                                 heterogeneity=heterogeneity, seed=seed)
-    grad_fn = jax.grad(model.loss)
+    # an expert model's gradient also gives its routing counters, which
+    # the telemetry sums into each round's event
+    grad_fn = (StatsGrad(model.loss_and_stats) if cfg.n_experts
+               else jax.grad(model.loss))
 
     def batches_for(r):
         toks = ds.sample_round(r, tau)  # [tau, C, B, S]

@@ -92,13 +92,23 @@ def mask_fn(kind: str, *, window: int = 0, chunk: int = 0):
 
 
 # ------------------------------------------------------------- naive oracle
-def attend_naive(q, k, v, allowed, *, q_positions=None, k_positions=None):
-    """q [B,S,H,D], k/v [B,T,H,D] (heads already matched). Oracle path."""
+def _scaled(scores, head_dim: int, scale):
+    """Scores times ``scale`` (None: over sqrt(head_dim))."""
+    if scale is None:
+        return scores / jnp.sqrt(head_dim)
+    return scores * scale
+
+
+def attend_naive(q, k, v, allowed, *, q_positions=None, k_positions=None,
+                 scale=None):
+    """q [B,S,H,D], k/v [B,T,H,D] (heads already matched). Oracle path.
+    ``scale`` multiplies the scores (None: 1/sqrt(D))."""
     B, S, H, D = q.shape
     T = k.shape[1]
     qp = jnp.arange(S) if q_positions is None else q_positions
     kp = jnp.arange(T) if k_positions is None else k_positions
-    scores = jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32) / jnp.sqrt(D)
+    scores = _scaled(jnp.einsum("bshd,bthd->bhst", q, k).astype(jnp.float32),
+                     D, scale)
     mask = allowed(qp[:, None], kp[None, :])  # [S, T]
     scores = jnp.where(mask[None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -106,7 +116,7 @@ def attend_naive(q, k, v, allowed, *, q_positions=None, k_positions=None):
 
 
 # ------------------------------------------------------ blockwise (flash)
-def attend_blockwise(q, k, v, allowed, *, block_size: int = 512):
+def attend_blockwise(q, k, v, allowed, *, block_size: int = 512, scale=None):
     """Streaming-softmax attention, scanning KV blocks. Memory per step is
     O(S * block) instead of O(S^2). Matches attend_naive to float tolerance
     (property-tested in tests/test_attention.py).
@@ -135,9 +145,9 @@ def attend_blockwise(q, k, v, allowed, *, block_size: int = 512):
         acc, m, denom = carry  # [B,S,Hkv,G,D] f32, [B,S,Hkv,G] x2
         blk_idx, kblk, vblk = inp
         kpos = blk_idx * block_size + jnp.arange(block_size)
-        scores = jnp.einsum("bshgd,bthd->bshgt", qg, kblk,
-                            preferred_element_type=jnp.float32)
-        scores = scores / jnp.sqrt(D)
+        scores = _scaled(jnp.einsum("bshgd,bthd->bshgt", qg, kblk,
+                                    preferred_element_type=jnp.float32),
+                         D, scale)
         ok = allowed(qpos[:, None], kpos[None, :]) & (kpos < T)[None, :]
         scores = jnp.where(ok[None, :, None, None, :], scores, NEG_INF)
         m_blk = jnp.max(scores, axis=-1)
@@ -169,8 +179,10 @@ def attention(params, x, *, n_heads: int, n_kv_heads: int, head_dim: int,
               kind: str = "causal", window: int = 0, chunk: int = 0,
               rope_theta: float = 1e4, use_rope: bool = True,
               positions=None, block_size: int = 512,
-              force_naive: bool = False, use_pallas: bool = False):
-    """Training / prefill attention over a full sequence. Returns [B,S,d]."""
+              force_naive: bool = False, use_pallas: bool = False,
+              scale=None):
+    """Training / prefill attention over a full sequence. Returns [B,S,d].
+    ``scale`` multiplies the attention scores (None: 1/sqrt(head_dim))."""
     B, S, _ = x.shape
     q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
     q, k = _qk_norm(params, q, k)
@@ -183,6 +195,8 @@ def attention(params, x, *, n_heads: int, n_kv_heads: int, head_dim: int,
     if use_pallas and not force_naive:
         from repro.kernels import ops as kops
 
+        if scale is not None:   # the kernel divides by sqrt(head_dim)
+            q = q * (scale * head_dim ** 0.5)
         out = kops.flash_attention(
             q, k, v, kind=("causal" if kind == "full" else kind),
             window=window, chunk=chunk,
@@ -197,10 +211,11 @@ def attention(params, x, *, n_heads: int, n_kv_heads: int, head_dim: int,
         if groups > 1:
             k = jnp.repeat(k, groups, axis=2)
             v = jnp.repeat(v, groups, axis=2)
-        out = attend_naive(q, k, v, allowed)
+        out = attend_naive(q, k, v, allowed, scale=scale)
     else:
         # blockwise path handles GQA in grouped form (no KV repeat)
-        out = attend_blockwise(q, k, v, allowed, block_size=block_size)
+        out = attend_blockwise(q, k, v, allowed, block_size=block_size,
+                               scale=scale)
     out = out.reshape(B, S, n_heads * head_dim) @ params["wo"]
     if "bo" in params:
         out = out + params["bo"]
@@ -262,7 +277,7 @@ def append_to_cache(cache: KVCache, k1, v1, *, ring: bool = False) -> KVCache:
 
 
 def attend_decode(q1, cache: KVCache, *, window: int = 0, chunk: int = 0,
-                  kind: str = "full"):
+                  kind: str = "full", scale=None):
     """One-token attention vs cache, grouped-query form (no KV repeat).
 
     q1: [B, Hq, D]. Returns [B, Hq, D]. The cache slot positions (absolute)
@@ -275,8 +290,8 @@ def attend_decode(q1, cache: KVCache, *, window: int = 0, chunk: int = 0,
     G = Hq // Hkv
     qg = q1.reshape(B, Hkv, G, D)
     t = cache.length - 1  # absolute position of the query token
-    scores = jnp.einsum("bhgd,bshd->bhgs", qg.astype(jnp.float32),
-                        cache.k.astype(jnp.float32)) / jnp.sqrt(D)
+    scores = _scaled(jnp.einsum("bhgd,bshd->bhgs", qg.astype(jnp.float32),
+                                cache.k.astype(jnp.float32)), D, scale)
     kp = cache.pos
     ok = (kp >= 0) & (kp <= t)
     if kind == "sliding":
@@ -293,7 +308,7 @@ def attend_decode(q1, cache: KVCache, *, window: int = 0, chunk: int = 0,
 def decode_attention(params, x1, cache: KVCache, *, n_heads: int,
                      n_kv_heads: int, head_dim: int, kind: str = "full",
                      window: int = 0, chunk: int = 0, rope_theta: float = 1e4,
-                     use_rope: bool = True, ring: bool = False):
+                     use_rope: bool = True, ring: bool = False, scale=None):
     """Full decode step for one layer: project, rope at absolute position,
     append to cache, attend. x1: [B, 1, d]. Returns ([B, 1, d], new cache)."""
     B = x1.shape[0]
@@ -304,7 +319,8 @@ def decode_attention(params, x1, cache: KVCache, *, n_heads: int,
         q = apply_rope(q, pos, rope_theta)
         k = apply_rope(k, pos, rope_theta)
     cache = append_to_cache(cache, k, v, ring=ring)
-    out = attend_decode(q[:, 0], cache, window=window, chunk=chunk, kind=kind)
+    out = attend_decode(q[:, 0], cache, window=window, chunk=chunk, kind=kind,
+                        scale=scale)
     out = out.reshape(B, 1, n_heads * head_dim) @ params["wo"]
     if "bo" in params:
         out = out + params["bo"]
@@ -315,7 +331,7 @@ def prefill_attention(params, x, *, n_heads: int, n_kv_heads: int,
                       head_dim: int, cache: KVCache, kind: str = "full",
                       window: int = 0, chunk: int = 0, rope_theta: float = 1e4,
                       use_rope: bool = True, block_size: int = 512,
-                      ring: bool = False):
+                      ring: bool = False, scale=None):
     """Prefill: full-sequence attention AND populate the cache (post-RoPE)."""
     B, S, _ = x.shape
     q, k, v = _project_qkv(params, x, n_heads, n_kv_heads, head_dim)
@@ -332,9 +348,10 @@ def prefill_attention(params, x, *, n_heads: int, n_kv_heads: int,
         if groups > 1:
             k = jnp.repeat(k, groups, axis=2)
             v = jnp.repeat(v, groups, axis=2)
-        out = attend_naive(q, k, v, allowed)
+        out = attend_naive(q, k, v, allowed, scale=scale)
     else:
-        out = attend_blockwise(q, k, v, allowed, block_size=block_size)
+        out = attend_blockwise(q, k, v, allowed, block_size=block_size,
+                               scale=scale)
     out = out.reshape(B, S, n_heads * head_dim) @ params["wo"]
     if "bo" in params:
         out = out + params["bo"]

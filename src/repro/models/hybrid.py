@@ -87,7 +87,7 @@ class HybridLM(NamedTuple):
                     return apply_mamba_block(p, x, cfg), None
 
                 x, _ = jax.lax.scan(inner, x, gparams)
-                x, _ = apply_block(shared, x, cfg)
+                x = apply_block(shared, x, cfg)[0]
                 return x, None
 
             body = jax.checkpoint(group_body) if cfg.remat else group_body
@@ -96,7 +96,7 @@ class HybridLM(NamedTuple):
             for g in range(groups):
                 for p in params["groups"][g]:
                     x = apply_mamba_block(p, x, cfg)
-                x, _ = apply_block(shared, x, cfg)
+                x = apply_block(shared, x, cfg)[0]
         for p in params["rest"]:
             x = apply_mamba_block(p, x, cfg)
         return x

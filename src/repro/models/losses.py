@@ -50,8 +50,9 @@ def _grad_dtype_guard(x):
     return _guard(x, str(x.dtype))
 
 
-def chunked_ce(x, head, tokens, *, prefix: int = 0, chunk: int = CE_CHUNK):
-    """Mean next-token CE.
+def chunked_ce(x, head, tokens, *, prefix: int = 0, chunk: int = CE_CHUNK,
+               logits_scaling: float = 1.0):
+    """Mean next-token CE of the logits ``x @ head / logits_scaling``.
 
     x:      [B, S_total, d] final-norm hidden states
     head:   [d, V]
@@ -77,6 +78,8 @@ def chunked_ce(x, head, tokens, *, prefix: int = 0, chunk: int = CE_CHUNK):
     def body(acc, inp):
         x_c, t_c, m_c = inp                 # [B,c,d], [B,c], [c]
         logits = shard_logits((x_c @ head).astype(jnp.float32))
+        if logits_scaling != 1.0:
+            logits = logits / logits_scaling
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, t_c[..., None], axis=-1)[..., 0]
         return acc + jnp.sum((logz - gold) * m_c[None, :]), None

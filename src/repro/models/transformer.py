@@ -50,7 +50,9 @@ def init_block(key, cfg: ArchConfig):
     if cfg.n_experts:
         p["moe"] = init_moe(kmlp, cfg.d_model, cfg.d_ff, cfg.n_experts, dtype,
                             shared_expert=cfg.moe_shared_expert,
-                            activation=cfg.activation)
+                            activation=cfg.activation,
+                            router_experts=cfg.router_experts,
+                            expert_offset=cfg.expert_offset)
     else:
         p["mlp"] = init_mlp(kmlp, cfg.d_model, cfg.d_ff, dtype,
                             activation=cfg.activation, with_bias=cfg.mlp_bias)
@@ -58,28 +60,39 @@ def init_block(key, cfg: ArchConfig):
 
 
 def _apply_ffn(p, h, cfg: ArchConfig):
+    """(out, aux loss, routed slots per held expert or None)."""
     if cfg.n_experts:
-        out, aux = apply_moe(
+        return apply_moe(
             p["moe"], h, n_experts=cfg.n_experts, k=cfg.experts_per_token,
             capacity_factor=cfg.capacity_factor, activation=cfg.activation,
-            shared_expert=cfg.moe_shared_expert)
-        return out, aux
-    return apply_mlp(h, p["mlp"], activation=cfg.activation), jnp.zeros((), jnp.float32)
+            shared_expert=cfg.moe_shared_expert,
+            router_experts=cfg.router_experts, expert_offset=cfg.expert_offset)
+    return (apply_mlp(h, p["mlp"], activation=cfg.activation),
+            jnp.zeros((), jnp.float32), None)
+
+
+def _branch(h, cfg: ArchConfig):
+    """A block branch as it joins the residual stream (granite scales it)."""
+    if cfg.residual_multiplier != 1.0:
+        return h * cfg.residual_multiplier
+    return h
 
 
 def apply_block(p, x, cfg: ArchConfig):
-    """(x, aux) for one decoder block over a full sequence."""
+    """(x, aux, routed slots per held expert or None) for one decoder block
+    over a full sequence."""
     x = shard_residual(x)
     h = apply_norm(x, p["ln1"], cfg.norm)
     h = attn.attention(
         p["attn"], h, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.head_dim, kind=cfg.attention, window=cfg.window,
         chunk=cfg.chunk, rope_theta=cfg.rope_theta, use_rope=cfg.use_rope,
-        block_size=cfg.attn_block_size, use_pallas=cfg.use_pallas_attention)
-    x = x + h
+        block_size=cfg.attn_block_size, use_pallas=cfg.use_pallas_attention,
+        scale=cfg.attention_multiplier)
+    x = x + _branch(h, cfg)
     h = apply_norm(x, p["ln2"], cfg.norm)
-    h, aux = _apply_ffn(p, h, cfg)
-    return x + h, aux
+    h, aux, counts = _apply_ffn(p, h, cfg)
+    return x + _branch(h, cfg), aux, counts
 
 
 def apply_block_decode(p, x1, cache, cfg: ArchConfig, *, ring: bool):
@@ -88,11 +101,11 @@ def apply_block_decode(p, x1, cache, cfg: ArchConfig, *, ring: bool):
         p["attn"], h, cache, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads,
         head_dim=cfg.head_dim, kind=cfg.attention, window=cfg.window,
         chunk=cfg.chunk, rope_theta=cfg.rope_theta, use_rope=cfg.use_rope,
-        ring=ring)
-    x1 = x1 + h
+        ring=ring, scale=cfg.attention_multiplier)
+    x1 = x1 + _branch(h, cfg)
     h = apply_norm(x1, p["ln2"], cfg.norm)
-    h, _ = _apply_ffn(p, h, cfg)
-    return x1 + h, cache
+    h = _apply_ffn(p, h, cfg)[0]
+    return x1 + _branch(h, cfg), cache
 
 
 def apply_block_prefill(p, x, cache, cfg: ArchConfig, *, ring: bool):
@@ -102,11 +115,12 @@ def apply_block_prefill(p, x, cache, cfg: ArchConfig, *, ring: bool):
         p["attn"], h, cache=cache, n_heads=cfg.n_heads,
         n_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim, kind=cfg.attention,
         window=cfg.window, chunk=cfg.chunk, rope_theta=cfg.rope_theta,
-        use_rope=cfg.use_rope, block_size=cfg.attn_block_size, ring=ring)
-    x = x + h
+        use_rope=cfg.use_rope, block_size=cfg.attn_block_size, ring=ring,
+        scale=cfg.attention_multiplier)
+    x = x + _branch(h, cfg)
     h = apply_norm(x, p["ln2"], cfg.norm)
-    h, _ = _apply_ffn(p, h, cfg)
-    return x + h, cache
+    h = _apply_ffn(p, h, cfg)[0]
+    return x + _branch(h, cfg), cache
 
 
 # ---------------------------------------------------------------------- LM
@@ -139,55 +153,74 @@ class TransformerLM(NamedTuple):
         x = params["embed"][tokens]
         if cfg.embed_scale:
             x = x * jnp.sqrt(jnp.asarray(cfg.d_model, x.dtype))
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
         if image_embeds is not None:
             x = jnp.concatenate([image_embeds.astype(x.dtype), x], axis=1)
         return x.astype(jnp.dtype(cfg.dtype))
 
     def _stack(self, params, x):
+        """(x, aux loss, routed slots per held expert summed over layers —
+        None without experts)."""
         cfg = self.cfg
         aux_total = jnp.zeros((), jnp.float32)
+        slots = (jnp.zeros((cfg.n_experts,), jnp.int32) if cfg.n_experts
+                 else None)
         if cfg.scan_layers:
             def body(carry, p):
-                x, aux = carry
-                x, a = apply_block(p, x, cfg)
-                return (x, aux + a), None
+                x, aux, n = carry
+                x, a, c = apply_block(p, x, cfg)
+                return (x, aux + a, None if n is None else n + c), None
 
             body_fn = jax.checkpoint(body) if cfg.remat else body
-            (x, aux_total), _ = jax.lax.scan(body_fn, (x, aux_total),
-                                             params["layers"])
+            (x, aux_total, slots), _ = jax.lax.scan(
+                body_fn, (x, aux_total, slots), params["layers"])
         else:
             for p in params["layers"]:
-                x, a = apply_block(p, x, cfg)
+                x, a, c = apply_block(p, x, cfg)
                 aux_total = aux_total + a
-        return x, aux_total
+                slots = None if slots is None else slots + c
+        return x, aux_total, slots
 
     def _logits(self, params, x):
         cfg = self.cfg
         x = apply_norm(x, params["final_norm"], cfg.norm)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        return x @ head
+        logits = x @ head
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
 
     def forward(self, params, batch) -> jax.Array:
         """Full-sequence logits [B, S(+n_modal), V]."""
         x = self._embed(params, batch["tokens"], batch.get("image_embeds"))
-        x, _ = self._stack(params, x)
+        x = self._stack(params, x)[0]
         return self._logits(params, x)
 
     # ----------------------------------------------------------------- loss
     def loss(self, params, batch) -> jax.Array:
         """Next-token cross entropy (chunked; for VLM, text positions only)."""
+        return self.loss_and_stats(params, batch)[0]
+
+    def loss_and_stats(self, params, batch):
+        """(loss, counters): ``loss`` and, for a model with experts,
+        ``{"moe_expert_slots": [n_experts] int32}``, the routed slots that
+        landed on each held expert, summed over layers (``{}`` without
+        experts). ``repro.core.api.grad_with_stats`` differentiates it."""
         cfg = self.cfg
         from repro.models.losses import chunked_ce
 
         x = self._embed(params, batch["tokens"], batch.get("image_embeds"))
-        x, aux = self._stack(params, x)
+        x, aux, slots = self._stack(params, x)
         x = apply_norm(x, params["final_norm"], cfg.norm)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         n_img = 0
         if batch.get("image_embeds") is not None:
             n_img = batch["image_embeds"].shape[1]
-        ce = chunked_ce(x, head, batch["tokens"], prefix=n_img)
-        return ce + MOE_AUX_COEF * aux
+        ce = chunked_ce(x, head, batch["tokens"], prefix=n_img,
+                        logits_scaling=cfg.logits_scaling)
+        stats = {} if slots is None else {"moe_expert_slots": slots}
+        return ce + MOE_AUX_COEF * aux, stats
 
     # ---------------------------------------------------------------- serve
     def _ring(self) -> bool:

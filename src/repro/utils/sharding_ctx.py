@@ -44,6 +44,11 @@ def moe_shards():
     return getattr(_state, "moe_shards", None)
 
 
+def meshed() -> bool:
+    """Whether a production-mesh lowering has set the activation specs."""
+    return getattr(_state, "residual", None) is not None
+
+
 def shard_residual(x):
     spec = getattr(_state, "residual", None)
     if spec is None:

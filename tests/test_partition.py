@@ -118,3 +118,78 @@ def test_small_mesh_lowering_subprocess():
     )
     assert "TRAIN_OK" in res.stdout, res.stderr[-2000:]
     assert "DECODE_OK" in res.stdout, res.stderr[-2000:]
+
+
+MOE_MESH_SCRIPT = r"""
+import os
+import sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import dataclasses
+import json
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+from repro.configs import get_config, registry
+from repro.configs.base import INPUT_SHAPES, ShapeConfig
+from repro.launch.mesh import make_test_mesh
+from repro.launch.train import lower_train_step, make_plan
+from repro.models.moe import apply_moe, init_moe
+from repro.utils.sharding_ctx import activation_sharding
+
+E = int(sys.argv[1])
+mesh = make_test_mesh((2, 4), ("data", "model"))
+INPUT_SHAPES["train_4k"] = ShapeConfig("train_4k", 64, 4, "train")
+arch = "granite-moe-3b-a800m"
+cfg = dataclasses.replace(get_config(arch).reduced(), n_experts=E,
+                          router_experts=E, capacity_factor=float(E))
+registry()[arch] = cfg
+lower_train_step(make_plan(arch, mesh)).compile()
+print("TRAIN_OK")
+
+# the capacity dispatch under the mesh lowering's context against the
+# drop-free grouped layer off it, on the same weights and tokens
+params = init_moe(jax.random.key(0), cfg.d_model, cfg.d_ff, E, jnp.float32,
+                  shared_expert=False, activation=cfg.activation)
+x = jax.random.normal(jax.random.key(1), (4, 64, cfg.d_model), jnp.float32)
+kw = dict(n_experts=E, k=cfg.experts_per_token,
+          capacity_factor=cfg.capacity_factor, activation=cfg.activation,
+          shared_expert=False)
+layer = jax.jit(lambda p, a: apply_moe(p, a, **kw))
+want = layer(params, x)
+# the launcher's token-sharded dispatch when experts don't divide `model`
+moe = None if E % 4 == 0 else {"nb": 1, "ns": 4, "axes": ("model",),
+                               "spec": P("model", None, None)}
+with mesh, activation_sharding(residual=P(None, "model", None),
+                               logits=P(None, None, "model"),
+                               moe_shards=moe):
+    got = jax.jit(lambda p, a: apply_moe(p, a, **kw))(params, x)
+scale = float(jnp.max(jnp.abs(want[0])))
+print(json.dumps({"out_err": float(jnp.max(jnp.abs(got[0] - want[0]))) / scale,
+                  "aux": [float(got[1]), float(want[1])],
+                  "counts": [got[2].tolist(), want[2].tolist()]}))
+"""
+
+
+@pytest.mark.parametrize("n_experts", [4, 3], ids=["capacity", "token-sharded"])
+def test_moe_mesh_dispatch_matches_dropfree_subprocess(n_experts):
+    """A reduced expert model's round compiles on a 2x4 fake-device mesh,
+    and there its capacity dispatch (plain, and token-sharded when the
+    experts don't divide the model axis) gives the drop-free layer's
+    output at a capacity that drops nothing."""
+    import json
+
+    res = subprocess.run(
+        [sys.executable, "-c", MOE_MESH_SCRIPT, str(n_experts)],
+        capture_output=True, text=True, timeout=900,
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"},
+        cwd=__file__.rsplit("/tests/", 1)[0],
+    )
+    assert "TRAIN_OK" in res.stdout, res.stderr[-2000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    # float32 sums in another order: a few ulps of the output's scale
+    assert out["out_err"] < 1e-5, out
+    got, want = out["counts"]
+    assert got == want and sum(want) == 4 * 64 * 2, out
+    if n_experts % 4 == 0:  # one token stream: the same load-balance term
+        assert abs(out["aux"][0] - out["aux"][1]) < 1e-6, out

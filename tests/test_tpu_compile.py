@@ -4,8 +4,11 @@ described v5e chip that is not attached.
 Interpret mode (every other kernel test) accepts what Mosaic refuses: a
 block whose last two dims are not (8, 128)-aligned, or a grid step that
 needs more VMEM than the scoped limit. These compiles catch both at
-fedlm-100m's published widths. Nothing runs, so they say nothing about
-results or times.
+fedlm-100m's published widths. The expert layer's grouped product is
+compiled too, at granite-moe-3b-a800m's widths, under the engine's vmap
+over clients and the gradient: the TPU's ragged dot refuses a batch
+dimension, which the CPU's accepts. Nothing runs, so they say nothing
+about results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and under several test workers only
@@ -132,6 +135,19 @@ def test_client_sketch_reads_the_arena_in_place(compile_for_chip, rows,
     assert "parameter(0)" in defn, defn
     assert f"f32[4,{rows},1024]{{2,1,0:T(8,128)}}" in defn, defn
     assert " copy(" not in text and " pad(" not in text
+
+
+def test_grouped_matmul_compiles_vmapped_with_its_gradient(compile_for_chip):
+    from repro.models.moe import grouped_matmul
+
+    # two clients' routed slots (4 x 512 tokens, 8 a token), 10 experts
+    # of 1536 x 512
+    def grads(rows, w, sizes):
+        loss = lambda r, w, s: jnp.sum(grouped_matmul(r, w, s) ** 2)  # noqa: E731
+        return jax.vmap(jax.grad(loss, argnums=(0, 1)))(rows, w, sizes)
+
+    compile_for_chip(grads, ((2, 16384, 1536), F32), ((2, 10, 1536, 512), F32),
+                     ((2, 10), jnp.int32))
 
 
 def test_gossip_reduce_compiles(compile_for_chip, rows):
