@@ -179,24 +179,32 @@ def pack_rows(leaves, layout: ArenaLayout, lead: int | None = None):
     ``[(lead,) rows, LANES]`` buffer — the dither-packing path, which
     needs rows without the Arena wrapper.
 
-    Single-materialization schedule: leaves and their zero pads are
-    interleaved into ONE flat concatenate (zeros are broadcast constants),
-    so the packed buffer is written once — a per-leaf ``jnp.pad`` followed
-    by a concat would stream the model an extra time, which is the
-    dominant crossing cost of the arena round at DRAM-resident sizes."""
-    parts = []
-    dtype = layout.dtype
+    Each leaf becomes its own zero-padded ``[(lead,) nr, LANES]`` row
+    block and the blocks are concatenated along the rows axis, so the
+    buffer comes out in the shape and row-major layout of the arenas it
+    meets, and FedCET's ``v = x - a*g - a*d`` reads a packed gradient in
+    place. One flat ``[lead, rows * LANES]`` concatenate reshaped after
+    would be tiled for the flat shape on the TPU: XLA scales it by ``a``
+    there, in an arena-sized pass of its own, and relays it out inside
+    the update (tests/test_tpu_compile.py). For the same reason only a
+    leaf's partial last row is padded flat; the whole zero rows that
+    round the arena up to the sublane tile are padded as rows. Padded
+    flat, they sent the last leaf through the flat tiling and back, a
+    pass of its own, and compiling that took most of a round program's
+    compile time where the leaf held 47M elements."""
+    blocks = []
     for leaf, shape, nr in zip(leaves, layout.shapes, layout.rows_per_leaf):
-        n = math.prod(shape)
+        n, full = math.prod(shape), _rows_of(shape)
         flat = jnp.reshape(leaf, (n,) if lead is None else (lead, n))
-        parts.append(flat)
-        if nr * LANES != n:
-            pad_shape = ((nr * LANES - n,) if lead is None
-                         else (lead, nr * LANES - n))
-            parts.append(jnp.zeros(pad_shape, dtype))
-    flat = jnp.concatenate(parts, axis=-1)
-    shape = (layout.rows, LANES)
-    return jnp.reshape(flat, shape if lead is None else (lead,) + shape)
+        if full * LANES != n:
+            pad = [(0, 0)] * (flat.ndim - 1) + [(0, full * LANES - n)]
+            flat = jnp.pad(flat, pad)
+        block = jnp.reshape(flat, flat.shape[:-1] + (full, LANES))
+        if nr != full:
+            pad = [(0, 0)] * (block.ndim - 2) + [(0, nr - full), (0, 0)]
+            block = jnp.pad(block, pad)
+        blocks.append(block)
+    return jnp.concatenate(blocks, axis=-2)
 
 
 def unpack(arena: Arena):
@@ -228,15 +236,23 @@ def pin_layouts(tree):
     ``[clients, rows, LANES]`` buffer clients-minor across rounds and copy
     it back and forth every round (two copies a round and 2.6 GB more
     compiled peak on fedlm-100m with 4 clients and τ=2)."""
-    from jax.experimental.layout import Layout, with_layout_constraint
-
     def pin(a):
         if not isinstance(a, Arena):
             return a
-        order = tuple(range(jnp.ndim(a.data)))
-        return Arena(with_layout_constraint(a.data, Layout(order)), a.layout)
+        return Arena(row_major(a.data), a.layout)
 
     return jax.tree.map(pin, tree, is_leaf=lambda a: isinstance(a, Arena))
+
+
+def row_major(a):
+    """``a`` held in its default row-major layout. The gradient pack pins
+    its leaves so: left free, the TPU compiler keeps the gradient of a
+    layer-stacked weight ``[layers, clients, ...]``-major, as the layer
+    scan writes it, and copies it into row-major inside every gradient
+    call."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(a, Layout(tuple(range(jnp.ndim(a)))))
 
 
 def adapt_state(src, like):

@@ -737,27 +737,26 @@ class RoundEngine:
                 return vg(x, batch)
 
         if self.arena:
-            from repro.core.arena import Arena, pack, unpack
+            from repro.core.arena import Arena, pack, row_major, unpack
 
             base = gf
 
             # the model-apply boundary: the loss sees the real pytree, the
-            # engine sees the arena. The unpack is pure slicing — XLA fuses
-            # it into the gradient consumers (measured: unpack+grads costs
-            # ~the grads alone); the repack is the one real crossing per
-            # call. (Returning RAW grads and folding the pack into the
-            # spec's first consumer was tried and is SLOWER: outside the
-            # grad closure the unpacked x/d slices materialize as copies
-            # instead of fusing, so the per-leaf triad + concat streams the
-            # model twice more than pack-then-fused-triad. Keep the pack
-            # here.)
+            # engine sees the arena. The unpack is pure slicing that XLA
+            # fuses into the gradient's consumers; the pack writes g once,
+            # in the x and d arenas' own [clients, rows, LANES] layout, so
+            # the spec's v = x - a*g - a*d reads it in place in one pass
+            # (tests/test_tpu_compile.py). (Returning RAW grads and folding
+            # the pack into the spec's first consumer was slower on the
+            # CPU: outside the grad closure the unpacked x/d slices
+            # materialized as copies instead of fusing.)
             def arena_gf(x, batch):
                 if not isinstance(x, Arena):
                     return base(x, batch)
                 with tele.scope("fed.client_grad"):
                     g = vg(unpack(x), batch)
                 with tele.scope("fed.arena_pack"):
-                    return pack(g, x.layout)
+                    return pack(jax.tree.map(row_major, g), x.layout)
 
             gf = arena_gf
         if self.telemetry is None:

@@ -83,14 +83,23 @@ def _assert_equiv(arena_state, per_leaf_state, tol=TOL):
 
 
 # --------------------------------------------------- pack/unpack round-trip
-def _odd_tree(key, dtype=jnp.float64, lead=None):
-    """Leaf sizes chosen to exercise lane padding: none divides 1024."""
-    shapes = [("w", (3, 5)), ("b", (7,)), ("scalar", ()), ("big", (1030,)),
+#: leaf sizes chosen to exercise lane padding: none divides 1024.
+ODD_SHAPES = [("w", (3, 5)), ("b", (7,)), ("scalar", ()), ("big", (1030,)),
               ("nest_k", (2, 513))]
+#: leaves of whole 1024-lane rows: only the last is padded, and only with
+#: zero rows up to the sublane tile (6 rows -> 8).
+WHOLE_ROW_SHAPES = [("a", (3, 1024)), ("b", (2048,)), ("c", (2, 512))]
+
+
+def _tree(key, shapes, dtype=jnp.float64, lead=None):
     ks = jax.random.split(key, len(shapes))
     mk = lambda k, s: jax.random.normal(  # noqa: E731
         k, ((lead,) + s if lead is not None else s), dtype)
     return {name: mk(k, s) for (name, s), k in zip(shapes, ks)}
+
+
+def _odd_tree(key, dtype=jnp.float64, lead=None):
+    return _tree(key, ODD_SHAPES, dtype, lead)
 
 
 def test_pack_unpack_roundtrip_bitwise():
@@ -112,6 +121,40 @@ def test_pack_unpack_roundtrip_stacked():
     back = unpack(arena)
     for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(back)):
         assert bool(jnp.all(a == b))
+
+
+def _flat_concat_rows(leaves, lo, lead):
+    """The flat packing expression: every leaf and its zero pad in one
+    ``[(lead,) rows * 1024]`` concatenate, reshaped to rows afterwards."""
+    parts = []
+    for leaf, shape, nr in zip(leaves, lo.shapes, lo.rows_per_leaf):
+        n = int(np.prod(shape))
+        parts.append(jnp.reshape(leaf, (n,) if lead is None else (lead, n)))
+        pad = nr * 1024 - n
+        if pad:
+            parts.append(jnp.zeros((pad,) if lead is None else (lead, pad),
+                                   lo.dtype))
+    shape = (lo.rows, 1024) if lead is None else (lead, lo.rows, 1024)
+    return jnp.reshape(jnp.concatenate(parts, axis=-1), shape)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+@pytest.mark.parametrize("lead", [None, 2, 4])
+@pytest.mark.parametrize("shapes", [ODD_SHAPES, WHOLE_ROW_SHAPES],
+                         ids=["odd", "whole-rows"])
+def test_pack_rows_matches_flat_concatenate(shapes, lead, dtype):
+    """Row blocks concatenated along the rows axis give the flat packing's
+    values, zero pads and row order bit for bit, under jit too."""
+    from repro.core.arena import pack_rows
+
+    lo = ArenaLayout.for_tree(_tree(jax.random.key(6), shapes, dtype))
+    leaves = jax.tree.leaves(_tree(jax.random.key(7), shapes, dtype, lead))
+    want = _flat_concat_rows(leaves, lo, lead)
+    for got in (pack_rows(leaves, lo, lead=lead),
+                jax.jit(pack_rows, static_argnums=(1, 2))(leaves, lo, lead)):
+        assert got.shape == want.shape and got.dtype == want.dtype == dtype
+        assert bool(jnp.all(got == want))
+        assert bool(jnp.all(jnp.signbit(got) == jnp.signbit(want)))
 
 
 def test_pack_pads_are_zero():

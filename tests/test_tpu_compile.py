@@ -7,7 +7,9 @@ needs more VMEM than the scoped limit. These compiles catch both at
 fedlm-100m's published widths. The expert layer's grouped product is
 compiled too, at granite-moe-3b-a800m's widths, under the engine's vmap
 over clients and the gradient: the TPU's ragged dot refuses a batch
-dimension, which the CPU's accepts. Nothing runs, so they say nothing
+dimension, which the CPU's accepts. One FedCET round over the arena is
+compiled whole, at a small size, for the layout in which the packed
+gradient meets the local update. Nothing runs, so they say nothing
 about results or times.
 
 The topology is described inside a module fixture, never at import: only
@@ -165,6 +167,104 @@ def test_flash_attention_compiles(compile_for_chip, batch, seq, dtype):
         lambda q, k, v: KA.flash_attention(q, k, v, interpret=False),
         ((batch, seq, hq, hd), dtype), ((batch, seq, hkv, hd), dtype),
         ((batch, seq, hkv, hd), dtype))
+
+
+def _arena_round_hlo(one_chip, params, loss, batch_shapes, n, tau=2):
+    """Optimized HLO of one FedCET round over the arena at ``n`` clients,
+    compiled for the described chip; ``params`` maps leaf names to
+    shapes, ``batch_shapes`` batch keys to one client's batch shape."""
+    from repro.core.engine import make_round_runner, with_arena
+    from repro.core.fedcet import FedCET
+
+    params = {k: jax.ShapeDtypeStruct(s, F32) for k, s in params.items()}
+    algo = with_arena(FedCET(alpha=0.05, c=0.05, tau=tau, n_clients=n))
+    grad_fn = jax.grad(loss)
+    batches = {k: jax.ShapeDtypeStruct((1, tau, n) + s, F32,
+                                       sharding=one_chip)
+               for k, s in batch_shapes.items()}
+    first = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[2:], F32),
+                         batches)
+    with jax.enable_x64(False):
+        state = jax.eval_shape(lambda p, b: algo.init(grad_fn, p, b),
+                               params, first)
+        state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), state)
+        return make_round_runner(algo, grad_fn, donate=True).lower(
+            state, batches).compile().as_text()
+
+
+def _instructions(text):
+    """``{name: definition}`` of every instruction of an HLO text."""
+    defs = {}
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%[\w.-]+) = (.*)", ln)
+        if m:
+            defs[m.group(1)] = m.group(2)
+    return defs
+
+
+def test_local_update_reads_the_packed_gradient_in_place(one_chip,
+                                                        no_compile_cache):
+    """A FedCET round over the arena at 4 clients: the packed gradient
+    comes out in the x and d arenas' ``[clients, rows, 1024]`` layout, so
+    ``v = x - a*g - a*d`` is one fusion that reads it as it is. Packed as
+    one flat ``[clients, rows * 1024]`` buffer, it is scaled there in a
+    pass of its own and copied into the arenas' tiling inside the
+    update. The last leaf (``w2``) takes the zero rows up to the sublane
+    tile, and those are padded as rows, not in its flat shape."""
+    n, d_in, hidden, d_out = 4, 256, 1000, 300
+    params = {"w1": (d_in, hidden), "b1": (hidden,), "w2": (hidden, d_out),
+              "b2": (d_out,)}
+
+    def loss(p, b):
+        h = jnp.tanh(b["x"] @ p["w1"] + p["b1"])
+        return jnp.mean((h @ p["w2"] + p["b2"] - b["y"]) ** 2)
+
+    text = _arena_round_hlo(one_chip, params, loss,
+                            {"x": (8, d_in), "y": (8, d_out)}, n)
+    layout = ArenaLayout.for_tree(
+        {k: jax.ShapeDtypeStruct(s, F32) for k, s in params.items()})
+    rows, last = layout.rows, layout.rows_per_leaf[-1]
+    defs = _instructions(text)
+    for flat in (f"f32[{n},{rows * LANES}]", f"f32[{n},{last * LANES}]"):
+        assert not [k for k, v in defs.items() if v.startswith(flat)], flat
+    updates = [v for v in defs.values()
+               if v.startswith(f"f32[{n},{rows},{LANES}]")
+               and " fusion(" in v and "fed.local_update/sub" in v]
+    assert updates
+    for upd in updates:
+        body = re.search(r"calls=(%[\w.-]+)", upd).group(1)
+        comp = re.search(rf"^{re.escape(body)} .*?^}}", text, re.M | re.S)
+        assert " copy(" not in comp.group(0), comp.group(0)
+        args = re.search(r" fusion\((.*?)\), kind=", upd).group(1)
+        for arg in re.findall(r"%[\w.-]+", args):
+            assert " copy(" not in defs[arg], defs[arg]
+
+
+def test_layer_stacked_gradient_is_packed_without_a_copy(one_chip,
+                                                         no_compile_cache):
+    """A model whose layers are one stacked weight, applied by a scan:
+    the scan's backward writes that weight's gradient layer by layer. Its
+    pack pins it row-major, so the compiler lays the scan's output out
+    ``[clients, layers, ...]`` and hands it to the pack as it is; left
+    free, it keeps it layers-major and copies it into row-major inside
+    every gradient call."""
+    n, layers, width = 4, 3, 512
+    params = {"w": (layers, width, width), "b": (layers, width)}
+
+    def loss(p, b):
+        def layer(h, wb):
+            return jnp.tanh(h @ wb[0] + wb[1]), None
+
+        h, _ = jax.lax.scan(layer, b["x"], (p["w"], p["b"]))
+        return jnp.mean((h - b["y"]) ** 2)
+
+    text = _arena_round_hlo(one_chip, params, loss,
+                            {"x": (8, width), "y": (8, width)}, n)
+    stacked = f"f32[{n},{layers},{width},{width}]"
+    copies = [v for v in _instructions(text).values()
+              if v.startswith(stacked) and " copy(" in v]
+    assert not copies, copies
 
 
 @pytest.mark.parametrize("clients", [1, 2, 3, 4, 7, 16, 30, 64, 171, 256,
