@@ -89,34 +89,35 @@ algorithm without forking its round body, and compose in either order::
 
 Cohort execution
 ----------------
-:class:`CohortSpec` splits the round into two phases. Phase A is the
-per-client compute (``begin_round``, the tau-1 local scan, ``message``) —
-row-wise vmapped work whose per-row values are independent of the batch
-size, so running it on the gathered ``[cohort, ...]`` rows (the default
-``lowering="gather"``) or on the full ``[N, ...]`` store and gathering the
-results afterwards (``lowering="dense"``, the O(N) reference the
-equivalence tests pin against) yields identical cohort rows. Phase B is
-everything cross-client — message transforms, the delay buffer update, the
-weighted reduction, ``server_aggregate``, the participation freeze — and
-ALWAYS runs on cohort-sized arrays in BOTH lowerings, so the two lowerings
-agree bitwise and cross-client compressors (shared-scale quantizers,
-cross-client top-k) are simply defined OVER THE COHORT. Composition:
-``with_participation`` becomes a Bernoulli mask over the cohort slots
-(absent members freeze, exactly the dense discipline), ``with_delay``
-buffers index by GLOBAL client id (non-sampled clients' buffered messages
-keep aging; ``fresh_mask`` is evaluated at global ids so rr/fixed
-schedules are client-stable), hierarchical topologies reduce the cohort
-through :meth:`~repro.core.topology.Topology.reduce_cohort` (first-tier
-segment ids gathered at the cohort's global ids, so every edge aggregator
-still sees exactly its own members), and CommMeter bills uplink AND
-present-only downlink at the ``cohort/N`` duty cycle. Gossip mixing has no
-server to sample a cohort — ``with_cohort`` rejects it — and FedLin's
-spec-internal cross-client top-k (``k_frac < 1``) is rejected via
-``cohort_compatible``. The store scatter is ``x.at[idx].set(rows)`` on
-every ``[N, ...]`` leaf: donate the round carry
-(``make_round_runner(..., donate=True)``, the launch default) so XLA
-updates the store in place instead of copying O(N) state per round —
-benchmarks/cohort_scaling.py pins round time ~flat in N at fixed cohort.
+A cohort round runs the population round's body, :meth:`RoundEngine.round`,
+on the cohort's rows: it gathers them from the client-state store, runs
+the same two phases on them, and scatters them back. Phase A is the
+per-client compute (``begin_round``, the tau-1 local scan, ``message``):
+row-wise vmapped work whose per-row values do not depend on how many rows
+run beside them, so running it on the gathered ``[cohort, ...]`` rows (the
+default ``lowering="gather"``) or on the full ``[N, ...]`` store and
+gathering its outputs (``lowering="dense"``, the O(N) reference the
+equivalence tests pin against) yields identical rows. Phase B is
+everything cross-client (message transforms, the delay buffer, the
+weighted reduction, ``server_aggregate``, the participation freeze) and
+runs on the cohort's rows in both lowerings, so cross-client compressors
+(shared-scale quantizers, cross-client top-k) are defined OVER THE COHORT.
+What differs from a population round is only which rows and which
+reduction the body is given: ``with_participation`` draws its mask over
+the cohort slots; ``with_delay`` buffers index by GLOBAL client id (the
+body updates the cohort's gathered buffer rows; entries outside the
+cohort keep aging; ``fresh_mask`` is evaluated at global ids so rr/fixed
+schedules are client-stable); the reduction is the weighted cohort mean,
+or a hierarchy's :meth:`~repro.core.topology.Topology.reduce_cohort`
+(first-tier segment ids gathered at the cohort's global ids, so every
+edge aggregator still sees exactly its own members). CommMeter bills
+uplink AND present-only downlink at the ``cohort/N`` duty cycle. Gossip
+mixing has no server to sample a cohort — ``with_cohort`` rejects it —
+and FedLin's spec-internal cross-client top-k (``k_frac < 1``) is
+rejected via ``cohort_compatible``. The store scatter is
+``x.at[idx].set(rows)`` on every ``[N, ...]`` leaf: donate the round
+carry (``make_round_runner(..., donate=True)``, the launch default) so
+XLA updates the store in place instead of copying O(N) state per round.
 
 All five factories are EXACT no-ops at their identity settings
 (``rate >= 1.0``; ``k_frac >= 1.0 and not quantize``; delay ``fixed:0`` /
@@ -151,7 +152,7 @@ from repro.core.staleness import (
     parse_policy,
     weighted_client_mean,
 )
-from repro.core.topology import TopoState, parse_topology
+from repro.core.topology import Star, TopoState, parse_topology
 from repro.utils.tree import tree_client_mean
 
 
@@ -782,6 +783,11 @@ class RoundEngine:
         with tele.scope("fed.local_update"):
             return self.local_step(gf, state, batch, rctx)
 
+    def _message(self, gf, state, batch, rctx):
+        """The spec's ``message`` under the ``fed.local_update`` scope."""
+        with tele.scope("fed.local_update"):
+            return self.message(gf, state, batch, rctx)
+
     def _msg_shapes(self, gf, inner, init_batch):
         """Abstract (eval_shape) wire-message tree of the current state —
         shapes transform extras and stateful-topology tier memory."""
@@ -795,32 +801,62 @@ class RoundEngine:
         """Per-transform extra state, shaped from the (abstract) message."""
         return tuple(t.init_extra(msg_shapes) for t in self.transforms)
 
-    def _comm_step(self, gf, inner, extras, batch, rctx, agg, step,
-                   tstate=None, dstate=None, fresh=None, mask=None):
-        """The single aggregating step: message -> transforms -> [staleness
-        buffer] -> reduce -> apply. The only place a cross-client collective
-        fires. ``step`` is the state's step counter at round entry —
-        stochastic transforms derive their per-round PRNG key from it
-        (never reused across rounds; stack multiple stochastic transforms
-        with distinct seeds). With a topology attached, the reduction goes
-        through ``reduce_and_advance`` — the one place topology state
-        (resampled-graph index, tier-compression memory) moves — under
-        the ``mask``-derived weights (uniform, or the participation
-        mask; the delay path derives its own stale-policy weights
-        instead).
+    def _local_phase(self, gf, inner, batches, agg):
+        """Phase A, the per-client compute, over whatever rows ``inner``
+        holds: ``begin_round``, the tau-1 local steps under ``lax.scan``,
+        and the aggregating step's ``message``. Returns ``(inner, rctx,
+        msg, mctx, last_batch)``; ``batches`` leaves are ``[tau, rows,
+        ...]``."""
+        first_b = jax.tree.map(lambda b: b[0], batches)
+        inner, rctx = self.begin_round(gf, inner, first_b, agg)
 
-        With ``dstate``/``fresh`` set (a ``with_delay`` round), the wire
-        message lands in the server buffer only where ``fresh`` is true,
-        the stale policy turns buffer + ages into the aggregation mean,
-        and stale clients either apply the update with their BUFFERED own
+        if self.tau > 1:
+            local_b = jax.tree.map(lambda b: b[: self.tau - 1], batches)
+            counted = self.telemetry is not None and tele.collecting()
+
+            def body(s, b):
+                if not counted:
+                    return self._local_update(gf, s, b, rctx), None
+                with tele.counting() as counts:
+                    s = self._local_update(gf, s, b, rctx)
+                return s, counts
+
+            # muted: a capture inside the scan body would leak inner-scan
+            # tracers onto the round-level telemetry tape; the body's
+            # counters leave it as the scan's outputs instead.
+            with tele.muted():
+                inner, counts = jax.lax.scan(body, inner, local_b)
+            if counted:
+                tele.count(jax.tree.map(lambda a: jnp.sum(a, axis=0), counts))
+
+        last_b = jax.tree.map(lambda b: b[self.tau - 1], batches)
+        msg, mctx = self._message(gf, inner, last_b, rctx)
+        return inner, rctx, msg, mctx, last_b
+
+    def _comm_step(self, gf, inner, extras, batch, msg, mctx, rctx, reduce,
+                   step, dstate=None, fresh=None, mask=None, ids=None):
+        """Phase B, the single aggregating step over the rows phase A ran
+        on: transforms -> [staleness buffer] -> reduce -> apply. The only
+        place a cross-client collective fires. ``msg`` / ``mctx`` are the
+        rows' message and its client-local context; ``reduce`` is the
+        round's aggregating reduction (see :meth:`_aggregator`); ``ids``
+        holds a cohort's global client ids (None for the population).
+        ``step`` is the state's step counter at round entry — stochastic
+        transforms derive their per-round PRNG key from it (never reused
+        across rounds; stack multiple stochastic transforms with distinct
+        seeds).
+
+        With ``dstate``/``fresh`` set (a ``with_delay`` round; the rows of
+        the buffer, ``fresh`` over the same rows), the wire message lands
+        in the server buffer only where ``fresh`` is true, the stale
+        policy turns buffer + ages into the weights ``reduce`` takes, and
+        stale clients either apply the update with their BUFFERED own
         message (``last``/``poly`` — the copy both ends kept) or take the
         tau-th step as a pure local continuation (``drop``). Stale clients
         never transmitted, so their transform memory (error feedback /
         shift) reverts to its pre-round value. Returns
-        ``(inner, extras, dstate, tx)`` — ``tx`` is the post-transform
-        wire message (``init`` seeds the buffer from it)."""
-        with tele.scope("fed.local_update"):
-            msg, mctx = self.message(gf, inner, batch, rctx)
+        ``(inner, extras, tstate, dstate, tx)`` — ``tx`` is the
+        post-transform wire message (``init`` seeds the buffer from it)."""
         # observer-only telemetry: rec is False when telemetry is detached
         # (bitwise no-op) or no tape is active (init / direct round calls).
         rec = self.telemetry is not None and tele.collecting()
@@ -830,13 +866,13 @@ class RoundEngine:
                 if self.telemetry.leaf_stats:
                     tele.capture("leaf_msg_norm",
                                  tele.leaf_client_norms(msg))
-        if (dstate is None and self.delay is None and self.topology is None
-                and self.arena):
+        if (ids is None and dstate is None and self.delay is None
+                and self.topology is None and self.arena):
             with tele.scope("fed.aggregate"):
                 fused = self._fused_tail(inner, msg, mctx, extras, step, mask)
             if fused is not None:
                 inner, new_extras = fused
-                return inner, tuple(new_extras), tstate, None, None
+                return inner, tuple(new_extras), None, None, None
         raw = msg
         new_extras = []
         with tele.scope("fed.uplink"):
@@ -850,43 +886,31 @@ class RoundEngine:
                 if self.telemetry.wants_sketch("compress_err"):
                     tele.capture("compress_err_clients",
                                  jnp.sqrt(tele.client_sq_norms(diff)))
+                    if ids is not None:
+                        # finalize maps the sketch's top-k cohort slots
+                        # to global client ids through this.
+                        tele.capture("cohort_ids", ids.astype(jnp.int32))
                 if self.telemetry.leaf_stats:
                     tele.capture("leaf_compress_err",
                                  tele.leaf_client_norms(diff))
 
         if dstate is None:  # synchronous path (and always: init)
             with tele.scope("fed.aggregate"):
-                if self.topology is not None:
-                    msg_bar, tstate_next = self.topology.reduce_and_advance(
-                        msg, self._topo_weights(mask), tstate)
-                else:
-                    msg_bar, tstate_next = agg(msg), None
+                msg_bar, tstate = reduce(msg)
                 inner = self.server_aggregate(inner, msg, msg_bar, mctx,
                                               rctx)
-            return inner, tuple(new_extras), tstate_next, None, msg
+            return inner, tuple(new_extras), tstate, None, msg
 
-        # fresh arrivals replace the buffered copy and reset its age; the
-        # buffer is server state — it updates and ages every round.
+        # fresh arrivals replace the buffered copy and reset its age.
+        n = fresh.shape[0]
         with tele.scope("fed.aggregate"):
-            buf = select_clients(msg, dstate.buf, fresh, self.n_clients)
+            buf = select_clients(msg, dstate.buf, fresh, n)
             age = jnp.where(fresh, 0, dstate.age + 1).astype(dstate.age.dtype)
-            if rec:
-                with tele.scope("fed.telemetry"):
-                    tele.capture("fresh_count",
-                                 jnp.sum(fresh.astype(jnp.int32)))
-                    tele.capture("age_min", jnp.min(age))
-                    tele.capture("age_mean",
-                                 jnp.mean(age.astype(jnp.float32)))
-                    tele.capture("age_max", jnp.max(age))
+            # the stale policy's weights feed the same reduction as the
+            # synchronous path, so hierarchical aggregation composes with
+            # staleness with no extra code.
             w = self.delay.policy.weights(age, fresh)
-            # the stale policy's weights feed the TOPOLOGY's reduction (the
-            # same weighted seam as the synchronous path), so hierarchical /
-            # gossip aggregation composes with staleness with no extra code.
-            if self.topology is not None:
-                msg_bar, tstate_next = self.topology.reduce_and_advance(
-                    buf, w, tstate)
-            else:
-                msg_bar, tstate_next = weighted_client_mean(buf, w), None
+            msg_bar, tstate = reduce(buf, w)
             # each client's own-message slot is what the server attributed
             # to it: the fresh wire message where it landed, the buffer
             # elsewhere.
@@ -896,12 +920,10 @@ class RoundEngine:
             # step instead of the aggregation update (XLA CSEs the repeated
             # gradient evaluation at the same point).
             local = self._local_update(gf, inner, batch, rctx)
-            agg_inner = select_clients(agg_inner, local, fresh, self.n_clients)
-        new_extras = tuple(
-            select_clients(ne, e, fresh, self.n_clients)
-            for ne, e in zip(new_extras, extras))
-        return (agg_inner, new_extras, tstate_next,
-                DelayState(buf=buf, age=age), msg)
+            agg_inner = select_clients(agg_inner, local, fresh, n)
+        new_extras = tuple(select_clients(ne, e, fresh, n)
+                           for ne, e in zip(new_extras, extras))
+        return agg_inner, new_extras, tstate, DelayState(buf=buf, age=age), msg
 
     def _would_transmit(self, gf, inner, extras, batch):
         """The wire message the current state WOULD transmit (begin_round
@@ -914,7 +936,7 @@ class RoundEngine:
         return msg
 
     def _topo_weights(self, mask, n: int | None = None):
-        """The per-client weight vector a topology reduces under on
+        """The per-client weight vector a round reduces under on
         non-delayed rounds: uniform, or the participation mask. ``n``
         overrides the vector length (cohort rounds reduce over the
         cohort slots, not the full population)."""
@@ -922,30 +944,37 @@ class RoundEngine:
         return (mask.astype(ft) if mask is not None
                 else jnp.ones((n if n is not None else self.n_clients,), ft))
 
-    def _aggregator(self, mask, tstate):
-        """The round's READ-ONLY cross-client reduction (fed to
-        ``begin_round`` — e.g. FedLin's gradient exchange): the attached
-        topology's weighted reduce (uniform weights, or the participation
-        mask as weights; topology state frozen — only the aggregating
-        step advances it), else the star mean / masked mean the engine
-        always used."""
-        if self.topology is not None:
-            w = self._topo_weights(mask)
-            return lambda tr: self.topology.reduce(tr, w, tstate)
-        if mask is not None:
-            return lambda tr: masked_client_mean(tr, mask)
-        return tree_client_mean
+    def _aggregator(self, mask, tstate, ids=None):
+        """The round's two cross-client reductions over its rows (the
+        population, or the cohort whose global client ids are ``ids``):
+        ``(agg, reduce)``.
 
-    def _cohort_aggregator(self, mask, idx, tstate):
-        """The cohort round's READ-ONLY reduction over the gathered
-        ``[cohort, ...]`` rows: the topology's cohort reduce (fed the
-        cohort's GLOBAL ids so hierarchies route each member to its own
-        edge aggregator) or the weighted cohort mean."""
-        w = self._topo_weights(mask, self.cohort.size)
-        if self.topology is not None:
-            return lambda tr: self.topology.reduce_cohort(
-                tr, w, idx, self.n_clients, tstate)
-        return lambda tr: weighted_client_mean(tr, w)
+        ``agg(tree)`` is READ-ONLY (fed to ``begin_round`` — e.g. FedLin's
+        gradient exchange; topology state frozen). ``reduce(tree, w=None)
+        -> (mean, tstate)`` is the aggregating step's, the one place
+        topology state (resampled-graph index, tier-compression memory)
+        moves; it reduces under the round's own weights (uniform, or the
+        participation mask) or, on a delayed round, the stale policy's
+        ``w``. The population reduces through the topology's weighted
+        reduce, else the star mean / masked mean the engine always used; a
+        cohort through the topology's (or the star's) cohort reduce, fed
+        the global ids so a hierarchy routes each member to its own edge
+        aggregator."""
+        if self.topology is None and ids is None:
+            agg = (tree_client_mean if mask is None
+                   else lambda tr: masked_client_mean(tr, mask))
+            return agg, lambda tr, w=None: (
+                agg(tr) if w is None else weighted_client_mean(tr, w), None)
+        topo, N = self.topology or Star(), self.n_clients
+        if ids is None:
+            w0 = self._topo_weights(mask)
+            return (lambda tr: topo.reduce(tr, w0, tstate),
+                    lambda tr, w=None: topo.reduce_and_advance(
+                        tr, w0 if w is None else w, tstate))
+        w0 = self._topo_weights(mask, self.cohort.size)
+        return (lambda tr: topo.reduce_cohort(tr, w0, ids, N, tstate),
+                lambda tr, w=None: topo.reduce_cohort_and_advance(
+                    tr, w0 if w is None else w, ids, N, tstate))
 
     # -------------------------------------------------------------- protocol
     def init(self, grad_fn: GradFn, x0, init_batch):
@@ -978,10 +1007,10 @@ class RoundEngine:
                                               else None)
         tx = None
         if run_comm:
+            msg, mctx = self._message(gf, inner, init_batch, None)
             inner, extras, tstate, _, tx = self._comm_step(
-                gf, inner, extras, init_batch, rctx=None,
-                agg=self._aggregator(None, tstate), step=inner.t,
-                tstate=tstate)
+                gf, inner, extras, init_batch, msg, mctx, None,
+                self._aggregator(None, tstate)[1], step=inner.t)
         dstate = None
         if self.delay is not None:
             if tx is None:
@@ -999,246 +1028,98 @@ class RoundEngine:
         aggregation sits OUTSIDE the scan so the cross-pod all-reduce
         appears exactly once per round in the HLO.
 
-        With a cohort attached the round dispatches to
-        :meth:`_cohort_round` — same state layout, same hooks, O(cohort)
-        work."""
-        if self.cohort is not None:
-            return self._cohort_round(grad_fn, state, batches)
+        With a cohort attached the same phases run on the cohort's rows,
+        gathered from the client-state store and scattered back after the
+        participation freeze (see the module docstring's `Cohort
+        execution`) — same state layout, same hooks, O(cohort) work."""
         gf = self._grad(grad_fn)
         inner, extras, tstate, dstate = self._split(state)
+        N = self.n_clients
 
-        step0 = inner.t  # round-entry counter: keys masks AND compressors
+        step0 = inner.t  # round-entry counter: keys cohort, masks, dither
+        ids = (None if self.cohort is None
+               else self.cohort.indices(step0, self.tau, N))
+        n = N if ids is None else self.cohort.size
         mask = None
         if self.sampling is not None:
+            # over a cohort: Bernoulli participation within its slots.
             key = jax.random.fold_in(jax.random.key(self.sampling.seed),
-                                     jnp.asarray(inner.t, jnp.int32))
-            mask = participation_mask(key, self.n_clients, self.sampling.rate)
-        agg = self._aggregator(mask, tstate)
+                                     jnp.asarray(step0, jnp.int32))
+            mask = participation_mask(key, n, self.sampling.rate)
+        agg, reduce = self._aggregator(mask, tstate, ids)
         fresh = None
         if self.delay is not None:
-            fresh = self.delay.fresh_mask(step0, self.tau, self.n_clients)
+            # delay schedules key off GLOBAL client ids (an rr straggler
+            # stays the same physical client whichever round samples it).
+            fresh = self.delay.fresh_mask(step0, self.tau, N)
+            if ids is not None:
+                fresh = fresh[ids]
             if mask is not None:
                 fresh = jnp.logical_and(fresh, mask)  # absent can't deliver
-        if self.telemetry is not None and tele.collecting():
+        rec = self.telemetry is not None and tele.collecting()
+        if rec:
             with tele.scope("fed.telemetry"):
                 tele.capture("participating",
                              jnp.sum(mask.astype(jnp.int32))
                              if mask is not None
-                             else jnp.asarray(self.n_clients, jnp.int32))
-        frozen_inner, frozen_extras = inner, extras
+                             else jnp.asarray(n, jnp.int32))
 
-        first_b = jax.tree.map(lambda b: b[0], batches)
-        inner, rctx = self.begin_round(gf, inner, first_b, agg)
+        # the rows the round runs on: the population, or the cohort's
+        # gathered from the store (pre-round rows: the freeze reverts to
+        # them).
+        rows, rows_extras, rows_dstate = inner, extras, dstate
+        if ids is not None:
+            rows, rows_extras, rows_dstate = gather_clients(
+                (inner, extras, dstate), ids, N)
+        if ids is not None and self.cohort.lowering == "dense":
+            # the O(N) reference lowering: every client computes and the
+            # cohort's rows of the results feed phase B. Row-wise vmapped
+            # compute does not depend on the batch size, so they match the
+            # gather lowering's bitwise.
+            phase_a = gather_clients(self._local_phase(
+                gf, inner, batches,
+                lambda tr: agg(gather_clients(tr, ids, N))), ids, N)
+        else:
+            if ids is not None:
+                batches = jax.tree.map(
+                    lambda b: (b[:, ids] if getattr(b, "ndim", 0) >= 2
+                               and b.shape[1] == N else b), batches)
+            phase_a = self._local_phase(gf, rows, batches, agg)
+        st, rctx, msg, mctx, last_b = phase_a
 
-        if self.tau > 1:
-            local_b = jax.tree.map(lambda b: b[: self.tau - 1], batches)
-            counted = self.telemetry is not None and tele.collecting()
-
-            def body(s, b):
-                if not counted:
-                    return self._local_update(gf, s, b, rctx), None
-                with tele.counting() as counts:
-                    s = self._local_update(gf, s, b, rctx)
-                return s, counts
-
-            # muted: a capture inside the scan body would leak inner-scan
-            # tracers onto the round-level telemetry tape; the body's
-            # counters leave it as the scan's outputs instead.
-            with tele.muted():
-                inner, counts = jax.lax.scan(body, inner, local_b)
-            if counted:
-                tele.count(jax.tree.map(lambda a: jnp.sum(a, axis=0), counts))
-
-        last_b = jax.tree.map(lambda b: b[self.tau - 1], batches)
-        inner, extras, tstate, dstate, _ = self._comm_step(
-            gf, inner, extras, last_b, rctx, agg, step=step0,
-            tstate=tstate, dstate=dstate, fresh=fresh, mask=mask)
+        new, new_extras, tstate, new_dstate, _ = self._comm_step(
+            gf, st, rows_extras, last_b, msg, mctx, rctx, reduce, step0,
+            dstate=rows_dstate, fresh=fresh, mask=mask, ids=ids)
 
         if mask is not None:
             # absent clients keep their pre-round state entirely; the delay
             # buffer and the topology round index are SERVER/NETWORK state
             # and are never reverted — an absent client's last-known
             # message simply keeps aging.
-            inner = select_clients(inner, frozen_inner, mask, self.n_clients)
-            extras = tuple(select_clients(e, fe, mask, self.n_clients)
-                           for e, fe in zip(extras, frozen_extras))
-        return self._wrap(inner, extras, tstate, dstate)
-
-    def _cohort_round(self, grad_fn: GradFn, state, batches):
-        """One O(cohort) communication round (see the module docstring's
-        `Cohort execution`): select the cohort's global ids, gather their
-        rows from the client-state store, run phase A (per-client compute)
-        on the cohort, run phase B (all cross-client work) on cohort-sized
-        arrays, scatter the updated rows back. Non-cohort clients are
-        untouched except for server-side aging of their delay-buffer
-        entries — exactly how the dense engine treats absent clients."""
-        gf = self._grad(grad_fn)
-        inner, extras, tstate, dstate = self._split(state)
-        N, m, tau = self.n_clients, self.cohort.size, self.tau
-
-        step0 = inner.t  # round-entry counter: keys cohort, masks, dither
-        idx = self.cohort.indices(step0, tau, N)
-        mask = None
-        if self.sampling is not None:
-            # Bernoulli participation WITHIN the cohort: a sampled-but-
-            # absent member freezes, like any absent client in dense mode.
-            key = jax.random.fold_in(jax.random.key(self.sampling.seed),
-                                     jnp.asarray(step0, jnp.int32))
-            mask = participation_mask(key, m, self.sampling.rate)
-        fresh = None
-        if self.delay is not None:
-            # delay schedules key off GLOBAL client ids (an rr straggler
-            # stays the same physical client whichever round samples it).
-            fresh = self.delay.fresh_mask(step0, tau, N)[idx]
-            if mask is not None:
-                fresh = jnp.logical_and(fresh, mask)
-        agg = self._cohort_aggregator(mask, idx, tstate)
-
-        frozen_inner = gather_clients(inner, idx, N)  # pre-round rows
-        extras_c = tuple(gather_clients(e, idx, N) for e in extras)
-
-        # ---- phase A: per-client compute (begin_round -> scan -> message)
-        if self.cohort.lowering == "dense":
-            # O(N) reference lowering: every client computes, only the
-            # cohort's rows feed phase B. Row-wise vmapped compute is
-            # batch-size independent, so the gathered results match the
-            # gather lowering bitwise.
-            dense_agg = lambda tr: agg(gather_clients(tr, idx, N))  # noqa: E731
-            first_b = jax.tree.map(lambda b: b[0], batches)
-            st, rctx = self.begin_round(gf, inner, first_b, dense_agg)
-            if tau > 1:
-                local_b = jax.tree.map(lambda b: b[: tau - 1], batches)
-                with tele.muted():
-                    st, _ = jax.lax.scan(
-                        lambda s, b: (self._local_update(gf, s, b, rctx),
-                                      None),
-                        st, local_b)
-            last_b = jax.tree.map(lambda b: b[tau - 1], batches)
-            with tele.scope("fed.local_update"):
-                msg, mctx = self.message(gf, st, last_b, rctx)
-            inner_c = gather_clients(st, idx, N)
-            msg_c = gather_clients(msg, idx, N)
-            mctx_c = gather_clients(mctx, idx, N)
-            rctx_c = gather_clients(rctx, idx, N)
-            last_b_c = gather_clients(last_b, idx, N)
-        else:
-            inner_c = gather_clients(inner, idx, N)
-            batches_c = jax.tree.map(
-                lambda b: (b[:, idx] if getattr(b, "ndim", 0) >= 2
-                           and b.shape[1] == N else b), batches)
-            first_b = jax.tree.map(lambda b: b[0], batches_c)
-            inner_c, rctx_c = self.begin_round(gf, inner_c, first_b, agg)
-            if tau > 1:
-                local_b = jax.tree.map(lambda b: b[: tau - 1], batches_c)
-                with tele.muted():
-                    inner_c, _ = jax.lax.scan(
-                        lambda s, b: (self._local_update(gf, s, b, rctx_c),
-                                      None),
-                        inner_c, local_b)
-            last_b_c = jax.tree.map(lambda b: b[tau - 1], batches_c)
-            with tele.scope("fed.local_update"):
-                msg_c, mctx_c = self.message(gf, inner_c, last_b_c, rctx_c)
-
-        # ---- phase B: transforms -> [buffer] -> reduce -> apply, all on
-        # cohort-sized arrays in BOTH lowerings (shared code = bitwise
-        # lowering equivalence; cross-client ops are per-cohort by design).
-        rec = self.telemetry is not None and tele.collecting()
-        if rec:
+            new = select_clients(new, rows, mask, n)
+            new_extras = tuple(select_clients(e, fe, mask, n)
+                               for e, fe in zip(new_extras, rows_extras))
+        if ids is not None:
+            new = scatter_clients(inner, new, ids, N)
+            new_extras = tuple(scatter_clients(e, r, ids, N)
+                               for e, r in zip(extras, new_extras))
+            if dstate is not None:
+                # the buffer is server state: every entry outside the
+                # cohort keeps aging (its owner could not deliver).
+                new_dstate = DelayState(
+                    buf=scatter_clients(dstate.buf, new_dstate.buf, ids, N),
+                    age=(dstate.age + 1).astype(dstate.age.dtype
+                                                ).at[ids].set(new_dstate.age))
+        if rec and new_dstate is not None:
+            # arrivals over the round's rows; ages over the whole server
+            # buffer (outside a cohort, entries keep aging).
             with tele.scope("fed.telemetry"):
-                tele.capture("msg_norm", tele.mean_client_norm(msg_c))
-                tele.capture("participating",
-                             jnp.sum(mask.astype(jnp.int32))
-                             if mask is not None
-                             else jnp.asarray(m, jnp.int32))
-                if self.telemetry.leaf_stats:
-                    tele.capture("leaf_msg_norm",
-                                 tele.leaf_client_norms(msg_c))
-        tx_c = msg_c
-        new_extras_c = []
-        with tele.scope("fed.uplink"):
-            for t, e in zip(self.transforms, extras_c):
-                tx_c, e = t.apply(tx_c, e, step0)
-                new_extras_c.append(e)
-        new_extras_c = tuple(new_extras_c)
-        if rec and self.transforms:
-            with tele.scope("fed.telemetry"):
-                diff_c = jax.tree.map(lambda a, b: a - b, tx_c, msg_c)
-                tele.capture("compress_err", tele.mean_client_norm(diff_c))
-                if self.telemetry.wants_sketch("compress_err"):
-                    # cohort-sized wire data — finalize translates top-k
-                    # slots to GLOBAL client ids through the captured
-                    # cohort index.
-                    tele.capture("compress_err_clients",
-                                 jnp.sqrt(tele.client_sq_norms(diff_c)))
-                    tele.capture("cohort_ids", idx.astype(jnp.int32))
-                if self.telemetry.leaf_stats:
-                    tele.capture("leaf_compress_err",
-                                 tele.leaf_client_norms(diff_c))
-
-        if dstate is None:
-            with tele.scope("fed.aggregate"):
-                if self.topology is not None:
-                    msg_bar, tstate = self.topology.reduce_cohort_and_advance(
-                        tx_c, self._topo_weights(mask, m), idx, N, tstate)
-                else:
-                    msg_bar = weighted_client_mean(
-                        tx_c, self._topo_weights(mask, m))
-                inner_c = self.server_aggregate(inner_c, tx_c, msg_bar,
-                                                mctx_c, rctx_c)
-            dstate_next = None
-        else:
-            with tele.scope("fed.aggregate"):
-                buf_c = gather_clients(dstate.buf, idx, N)
-                buf_c = select_clients(tx_c, buf_c, fresh, m)
-                age_c = jnp.where(fresh, 0, dstate.age[idx] + 1
-                                  ).astype(dstate.age.dtype)
-                w = self.delay.policy.weights(age_c, fresh)
-                if self.topology is not None:
-                    msg_bar, tstate = self.topology.reduce_cohort_and_advance(
-                        buf_c, w, idx, N, tstate)
-                else:
-                    msg_bar = weighted_client_mean(buf_c, w)
-                agg_inner_c = self.server_aggregate(inner_c, buf_c, msg_bar,
-                                                    mctx_c, rctx_c)
-            if not self.delay.policy.apply_stale:
-                local = self._local_update(gf, inner_c, last_b_c, rctx_c)
-                agg_inner_c = select_clients(agg_inner_c, local, fresh, m)
-            inner_c = agg_inner_c
-            new_extras_c = tuple(select_clients(ne, e, fresh, m)
-                                 for ne, e in zip(new_extras_c, extras_c))
-            # the buffer is server state: every non-cohort entry keeps
-            # aging (its owner could not deliver), cohort entries land.
-            dstate_next = DelayState(
-                buf=jax.tree.map(
-                    lambda o, r: (o.at[idx].set(r)
-                                  if getattr(o, "ndim", 0) >= 1
-                                  and o.shape[0] == N else r),
-                    dstate.buf, buf_c),
-                age=(dstate.age + 1).astype(dstate.age.dtype
-                                            ).at[idx].set(age_c))
-            if rec:
-                # cohort arrivals; ages summarize the FULL server buffer
-                # (non-cohort entries keep aging — the system-wide view).
-                with tele.scope("fed.telemetry"):
-                    tele.capture("fresh_count",
-                                 jnp.sum(fresh.astype(jnp.int32)))
-                    tele.capture("age_min", jnp.min(dstate_next.age))
-                    tele.capture("age_mean", jnp.mean(
-                        dstate_next.age.astype(jnp.float32)))
-                    tele.capture("age_max", jnp.max(dstate_next.age))
-
-        if mask is not None:
-            # absent cohort members keep their pre-round rows entirely
-            # (the dense engine's participation freeze, per-cohort).
-            inner_c = select_clients(inner_c, frozen_inner, mask, m)
-            new_extras_c = tuple(select_clients(e, fe, mask, m)
-                                 for e, fe in zip(new_extras_c, extras_c))
-
-        # ---- scatter the cohort rows back into the client-state store
-        inner_next = scatter_clients(inner, inner_c, idx, N)
-        extras_next = tuple(scatter_clients(e, ec, idx, N)
-                            for e, ec in zip(extras, new_extras_c))
-        return self._wrap(inner_next, extras_next, tstate, dstate_next)
+                tele.capture("fresh_count", jnp.sum(fresh.astype(jnp.int32)))
+                tele.capture("age_min", jnp.min(new_dstate.age))
+                tele.capture("age_mean",
+                             jnp.mean(new_dstate.age.astype(jnp.float32)))
+                tele.capture("age_max", jnp.max(new_dstate.age))
+        return self._wrap(new, new_extras, tstate, new_dstate)
 
 
 # ------------------------------------------------------- transform factories

@@ -129,6 +129,38 @@ def test_rr_selector_covers_population():
     assert seen == set(range(N))
 
 
+
+# ------------------------------------------------------------- counters
+@pytest.mark.parametrize("lowering", ["population", "gather", "dense"])
+def test_round_counts_every_local_step(lowering):
+    """A round's per-call counters sum over all tau gradient calls — the
+    tau-1 local steps of the muted scan and the aggregating step's — on
+    the rows each call runs on: the cohort's in the gather lowering,
+    every client's in the population round and the dense reference."""
+    from repro.core import make_round_runner, split_metrics, with_telemetry
+    from repro.core.api import StatsGrad
+
+    tau = 3
+
+    def loss_and_stats(x, batch):
+        return PROB.client_loss(x, batch), {
+            "grad_rows": jnp.ones((), jnp.int32)}
+
+    grad = StatsGrad(loss_and_stats)
+    batches = PROB.stacked_batches(tau)
+    algo = FedCET(alpha=0.02, c=0.3, tau=tau, n_clients=N)
+    rows = N
+    if lowering != "population":
+        algo = with_cohort(algo, CohortSpec(size=M, lowering=lowering))
+        rows = M if lowering == "gather" else N
+    algo = with_telemetry(algo, True)
+    state = algo.init(grad, jnp.zeros((PROB.dim,), PROB.b.dtype),
+                      jax.tree.map(lambda b: b[0], batches))
+    _, ys = make_round_runner(algo, grad, repeat=True)(state, batches, 2)
+    counted = split_metrics(algo, ys)[1]["grad_rows"]
+    assert [int(c) for c in counted] == [tau * rows] * 2
+
+
 # ------------------------------------------------------- checkpoint/resume
 def test_cohort_checkpoint_resume_mid_sweep(tmp_path):
     """Save after 4 rounds, reload, run 4 more — identical to 8 straight
